@@ -20,8 +20,11 @@ the minor grid dimension:
 
 The fp32 running-softmax accumulators live in VMEM scratch and persist
 across both phases — one softmax over the concatenated context, never a
-materialized (S, P+S) score matrix.  GQA rides the index maps exactly as
-in ``flash_attention``: K/V specs map query head ``h`` to ``h // G``.
+materialized (S, P+S) score matrix.  The wrapper moves heads ahead of
+the sequence (``(B, heads, seq, hd)``) so every block's last two dims
+are ``(block, hd)``, as the TPU lowering requires, and passes
+``prefix_len`` as a scalar-prefetch operand (SMEM).  GQA rides the index
+maps: K/V specs map query head ``h`` to ``h // G``.
 """
 
 from __future__ import annotations
@@ -41,7 +44,7 @@ def _kernel(plen_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref,
             m_scr, l_scr, acc_scr, *, scale, n_p, n_s, block_p, block_s):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
-    plen = plen_ref[0]
+    plen = plen_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -65,9 +68,9 @@ def _kernel(plen_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref,
     # ---- phase 1: cached prefix pages, masked by the per-row prefix_len
     @pl.when(jnp.logical_and(ki < n_p, ki * block_p < plen))
     def _prefix():
-        q = q_ref[0, :, 0, :]                     # (cq, hd)
-        k = kp_ref[0, :, 0, :]                    # (cp, hd)
-        v = vp_ref[0, :, 0, :]
+        q = q_ref[0, 0]                           # (cq, hd)
+        k = kp_ref[0, 0]                          # (cp, hd)
+        v = vp_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -78,14 +81,14 @@ def _kernel(plen_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref,
 
     # ---- phase 2: causal suffix (suffix-local coordinates)
     si = ki - n_p
-    q_len = q_ref.shape[1]
+    q_len = q_ref.shape[2]
 
     @pl.when(jnp.logical_and(ki >= n_p,
                              si * block_s <= qi * q_len + q_len - 1))
     def _suffix():
-        q = q_ref[0, :, 0, :]
-        k = ks_ref[0, :, 0, :]
-        v = vs_ref[0, :, 0, :]
+        q = q_ref[0, 0]
+        k = ks_ref[0, 0]
+        v = vs_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -97,7 +100,7 @@ def _kernel(plen_ref, q_ref, kp_ref, vp_ref, ks_ref, vs_ref, o_ref,
 
     @pl.when(ki == n_p + n_s - 1)
     def _finalize():
-        o_ref[0, :, 0, :] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
 def _divisor_block(n: int, target: int) -> int:
@@ -135,37 +138,37 @@ def chunked_prefill_attention(
         _kernel, scale=scale, n_p=n_p, n_s=n_s,
         block_p=block_p, block_s=block_s,
     )
+    heads_major = lambda x: jnp.swapaxes(x, 1, 2)  # (B,S,h,hd) → (B,h,S,hd)
+    prefix_blk = lambda b, h, qi, ki, plen: (b, h // G,
+                                             jnp.minimum(ki, n_p - 1), 0)
+    suffix_blk = lambda b, h, qi, ki, plen: (b, h // G,
+                                             jnp.maximum(ki - n_p, 0), 0)
     # the minor dim covers prefix pages then suffix blocks; each spec
     # clamps its index so the "other" phase re-fetches a resident block
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # prefix_len
         grid=(B, H, n_q, n_p + n_s),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, qi, ki: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_p, 1, hd),
-                         lambda b, h, qi, ki: (b, jnp.minimum(ki, n_p - 1),
-                                               h // G, 0)),
-            pl.BlockSpec((1, block_p, 1, hd),
-                         lambda b, h, qi, ki: (b, jnp.minimum(ki, n_p - 1),
-                                               h // G, 0)),
-            pl.BlockSpec((1, block_s, 1, hd),
-                         lambda b, h, qi, ki: (b, jnp.maximum(ki - n_p, 0),
-                                               h // G, 0)),
-            pl.BlockSpec((1, block_s, 1, hd),
-                         lambda b, h, qi, ki: (b, jnp.maximum(ki - n_p, 0),
-                                               h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, hd),
+                         lambda b, h, qi, ki, plen: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_p, hd), prefix_blk),
+            pl.BlockSpec((1, 1, block_p, hd), prefix_blk),
+            pl.BlockSpec((1, 1, block_s, hd), suffix_blk),
+            pl.BlockSpec((1, 1, block_s, hd), suffix_blk),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, hd),
+                               lambda b, h, qi, ki, plen: (b, h, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
         interpret=interpret,
-    )(prefix_len.astype(jnp.int32), q, k_prefix, v_prefix,
-      k_suffix, v_suffix)
+    )(prefix_len.astype(jnp.int32), heads_major(q), heads_major(k_prefix),
+      heads_major(v_prefix), heads_major(k_suffix), heads_major(v_suffix))
+    return heads_major(out)

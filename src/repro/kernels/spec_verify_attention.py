@@ -11,13 +11,14 @@ context positions ``< cache_len[b] + j + 1`` — its own (just written)
 position and everything before it, never the later draft positions.
 
 The page indirection is exactly :mod:`repro.kernels.paged_decode_attention`:
-grid ``(batch, kv_head, n_table_slots)`` with the table slot minor, K/V
-BlockSpec index maps resolving each slot to a physical pool page via the
-scalar-prefetched page table.  The only generalization is the query
-tile: all ``K × G`` (window × grouped-heads) queries of one KV head ride
-in a single ``(K·G, hd)`` VMEM tile — each cache byte is still read once
-per (row, kv-head) — and the per-page mask adds the query's window
-offset ``j = row // G`` to the length bound.  With ``K == 1`` the tile,
+grid ``(batch, n_table_slots)`` with the table slot minor, K/V BlockSpec
+index maps resolving each slot to a whole physical pool page (all KV
+heads) via the scalar-prefetched page table, and a loop over the KV
+heads in VMEM.  The only generalization is the query tile: all
+``K × G`` (window × grouped-heads) queries of one KV head ride in a
+single ``(K·G, hd)`` VMEM tile — each cache byte is still read once per
+(row, kv-head) — and the per-page mask adds the query's window offset
+``j = row // G`` to the length bound.  With ``K == 1`` the tile,
 the mask, and the accumulator update degenerate to the decode kernel's
 (the K=1 parity test pins this).
 """
@@ -36,9 +37,9 @@ NEG_INF = -1e30
 
 
 def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, scale, page, n_slots, K, G):
+            m_scr, l_scr, acc_scr, *, scale, page, n_slots, K, G, KV):
     b = pl.program_id(0)
-    si = pl.program_id(2)
+    si = pl.program_id(1)
     cache_len = len_ref[b]
 
     @pl.when(si == 0)
@@ -52,33 +53,34 @@ def _kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # 0; the fetch is never used)
     @pl.when(si * page < cache_len + K)
     def _compute():
-        q = q_ref[0, 0, :, :]                     # (K·G, hd)
-        k = k_ref[0, :, 0, :]                     # (page, hd)
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                  # (K·G, page)
-        KG, pk = s.shape
-        pos = si * page + jax.lax.broadcasted_iota(jnp.int32, (KG, pk), 1)
-        # query row r belongs to window position j = r // G and may see
-        # positions < cache_len + j + 1 (causal inside the window)
-        j = jax.lax.broadcasted_iota(jnp.int32, (KG, pk), 0) // G
-        s = jnp.where(pos < cache_len + j + 1, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_scr[...] = m_new
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        for h in range(KV):
+            q = q_ref[0, h]                        # (K·G, hd)
+            k = k_ref[0, :, h, :]                  # (page, hd)
+            v = v_ref[0, :, h, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                              # (K·G, page)
+            KG, pk = s.shape
+            pos = si * page + jax.lax.broadcasted_iota(jnp.int32, (KG, pk), 1)
+            # query row r belongs to window position j = r // G and may
+            # see positions < cache_len + j + 1 (causal inside the window)
+            j = jax.lax.broadcasted_iota(jnp.int32, (KG, pk), 0) // G
+            s = jnp.where(pos < cache_len + j + 1, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            m_scr[h] = m_new
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
 
     @pl.when(si == n_slots - 1)
     def _finalize():
-        o_ref[0, 0, :, :] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
 def spec_verify_attention(
@@ -107,31 +109,31 @@ def spec_verify_attention(
     qg = q.reshape(B, K, KV, G, hd).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(B, KV, K * G, hd)
     kernel = functools.partial(_kernel, scale=scale, page=page,
-                               n_slots=n_slots, K=K, G=G)
+                               n_slots=n_slots, K=K, G=G, KV=KV)
     # clamp: slots past the valid window still produce an in-bounds fetch
     # (skipped by pl.when); the table itself is engine-padded, this only
     # guards against garbage ids in the dead tail
     table = jnp.clip(page_table.astype(jnp.int32), 0, n_pages - 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # page table + cache_len drive the DMA
-        grid=(B, KV, n_slots),
+        grid=(B, n_slots),
         in_specs=[
-            pl.BlockSpec((1, 1, K * G, hd),
-                         lambda b, h, si, table_ref, len_ref: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda b, h, si, table_ref, len_ref:
-                         (table_ref[b, si], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, hd),
-                         lambda b, h, si, table_ref, len_ref:
-                         (table_ref[b, si], 0, h, 0)),
+            pl.BlockSpec((1, KV, K * G, hd),
+                         lambda b, si, table_ref, len_ref: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page, KV, hd),
+                         lambda b, si, table_ref, len_ref:
+                         (table_ref[b, si], 0, 0, 0)),
+            pl.BlockSpec((1, page, KV, hd),
+                         lambda b, si, table_ref, len_ref:
+                         (table_ref[b, si], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, K * G, hd),
-                               lambda b, h, si, table_ref, len_ref:
-                               (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, K * G, hd),
+                               lambda b, si, table_ref, len_ref:
+                               (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((K * G, 1), jnp.float32),
-            pltpu.VMEM((K * G, 1), jnp.float32),
-            pltpu.VMEM((K * G, hd), jnp.float32),
+            pltpu.VMEM((KV, K * G, 1), jnp.float32),
+            pltpu.VMEM((KV, K * G, 1), jnp.float32),
+            pltpu.VMEM((KV, K * G, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(

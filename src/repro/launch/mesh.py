@@ -25,13 +25,16 @@ from typing import Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # GSPMD propagation from the logical-axis rules needs Auto axes (the
+    # jax.make_mesh default became Explicit)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_serving_mesh(devices: Optional[Sequence] = None, *, tp: int) -> Mesh:

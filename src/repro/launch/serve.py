@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import argparse
 import os
+from pathlib import Path
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +45,49 @@ from repro.data.tokenizer import ByteTokenizer
 from repro.obs import TraceRecorder, write_chrome_trace
 from repro.serve import Cluster, ClusterClient, Engine, EngineClient, make_router
 from repro.models import init_params, model_specs
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first compile.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is (JAX reads
+    it itself).  Otherwise the cache sits at one fixed path in the
+    checkout, ``<repo>/.jax_cache`` (gitignored): the path is part of
+    the cache key, so it must not move between runs.  Every program is
+    cached, however fast it compiled: JAX's default skips those under a
+    second, most of an engine's.  Returns the directory in use.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
+
+
+def build_server(
+    cfg, params, tok, oracle: OracleLLM, *,
+    max_seq: int, slots: int, replicas: int = 1, tp: int = 1,
+    router: str = "affinity", trace=None,
+) -> Tuple[Any, Optional[Cluster]]:
+    """The serving stack joins talk to: one :class:`Engine` (tensor
+    -parallel over the first ``tp`` devices when ``tp > 1``) behind an
+    :class:`EngineClient`, or ``replicas`` engines behind the router in a
+    :class:`Cluster` and a :class:`ClusterClient`.  Engines keep their
+    defaults (paged KV, radix prefix cache).  Returns ``(client,
+    cluster)``; ``cluster`` is None for a single engine."""
+    if replicas > 1:
+        cluster = Cluster.replicate(
+            cfg, params, tok, replicas, router=make_router(router),
+            tp=tp, max_seq=max_seq, slots=slots, trace=trace)
+        return ClusterClient(cluster, oracle=oracle), cluster
+    mesh = None
+    if tp > 1:
+        from repro.launch.mesh import make_serving_mesh
+
+        mesh = make_serving_mesh(tp=tp)
+    engine = Engine(cfg, params, tok, max_seq=max_seq, slots=slots, mesh=mesh)
+    return EngineClient(engine, oracle=oracle, trace=trace), None
 
 
 def main() -> None:
@@ -73,8 +118,11 @@ def main() -> None:
                          "plus an export)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    params = init_params(model_specs(cfg), jax.random.PRNGKey(0), jnp.float32)
+    # published widths serve in bf16; the CPU smoke presets stay float32
+    dtype = jnp.float32 if args.smoke else jnp.bfloat16
+    params = init_params(model_specs(cfg), jax.random.PRNGKey(0), dtype)
     tok = ByteTokenizer(cfg.vocab_size)
 
     sc = {s.name: s for s in all_scenarios()}[args.scenario]
@@ -82,21 +130,9 @@ def main() -> None:
 
     trace = TraceRecorder() if args.trace_out else None
 
-    cluster = None
-    if args.replicas > 1:
-        cluster = Cluster.replicate(
-            cfg, params, tok, args.replicas, router=make_router(args.router),
-            tp=args.tp, max_seq=args.max_seq, slots=args.slots, trace=trace)
-        client = ClusterClient(cluster, oracle=oracle)
-    else:
-        mesh = None
-        if args.tp > 1:
-            from repro.launch.mesh import make_serving_mesh
-
-            mesh = make_serving_mesh(tp=args.tp)
-        engine = Engine(cfg, params, tok, max_seq=args.max_seq,
-                        slots=args.slots, mesh=mesh)
-        client = EngineClient(engine, oracle=oracle, trace=trace)
+    client, cluster = build_server(
+        cfg, params, tok, oracle, max_seq=args.max_seq, slots=args.slots,
+        replicas=args.replicas, tp=args.tp, router=args.router, trace=trace)
 
     try:
         if args.operator == "tuple":

@@ -190,7 +190,7 @@ def shard_residency_bytes(
     """
     from repro.models.params import abstract_params
 
-    mesh = jax.sharding.AbstractMesh((("model", int(tp)),))
+    mesh = jax.sharding.AbstractMesh((int(tp),), ("model",))
     tree = (abstract_quantized_params(specs, mesh, rules, dtype=dtype)
             if quant else abstract_params(specs, dtype, mesh, rules))
     total = 0

@@ -23,8 +23,10 @@ SCRIPT = textwrap.dedent("""
     from repro.checkpoint import save, restore, latest_step
 
     d = tempfile.mkdtemp()
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
-    mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    auto = (AxisType.Auto, AxisType.Auto)
+    mesh_a = jax.make_mesh((4, 2), ("data", "model"), axis_types=auto)
+    mesh_b = jax.make_mesh((2, 4), ("data", "model"), axis_types=auto)
 
     tree = {
         "w": jax.device_put(

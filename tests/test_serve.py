@@ -308,3 +308,29 @@ def test_hashword_tokenizer_roundtrip():
     text = "Find indexes x,y such that 3,4; Finished"
     ids = tok.encode(text, bos=False)
     assert tok.decode(ids) == text
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir_is_env_or_one_fixed_checkout_path(
+        monkeypatch, tmp_path, from_env):
+    """``enable_compile_cache`` keeps ``$JAX_COMPILATION_CACHE_DIR`` as
+    it is, else sets ``<repo>/.jax_cache`` (the same path every call),
+    and caches every program whatever its compile time.  JAX's config is
+    recorded, not changed: tests never turn the cache on."""
+    import pathlib
+
+    from repro.launch.serve import enable_compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want = str(tmp_path)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(pathlib.Path(__file__).resolve().parents[1] / ".jax_cache")
+    assert enable_compile_cache() == enable_compile_cache() == want
+    set_dirs = {v for k, v in updates if k == "jax_compilation_cache_dir"}
+    assert set_dirs == (set() if from_env else {want})
+    assert ("jax_persistent_cache_min_compile_time_secs", 0) in updates

@@ -65,7 +65,7 @@ def test_grok_overrides_merge_over_default_rules():
     from repro.configs import get_smoke_config
     from repro.sharding.logical import mesh_active, use_mesh
 
-    am = jax.sharding.AbstractMesh((("model", 32),))
+    am = jax.sharding.AbstractMesh((32,), ("model",))
     grok_rules = get_smoke_config("grok-1-314b").rules()
     assert grok_rules == {"experts": None, "expert_mlp": "model"}
     assert not mesh_active()
@@ -85,7 +85,7 @@ def test_shard_is_noop_outside_mesh():
     x = jax.numpy.ones((4, 8))
     assert shard(x, "batch", "embed") is x
     with pytest.raises(ValueError, match="rank mismatch"):
-        with use_mesh(jax.sharding.AbstractMesh((("model", 2),))):
+        with use_mesh(jax.sharding.AbstractMesh((2,), ("model",))):
             shard(x, "batch")
 
 
@@ -93,7 +93,7 @@ def test_abstract_mesh_resolution_matches_fake_mesh():
     """AbstractMesh exposes .shape as a name→size Mapping (no .devices);
     MeshContext.resolve must agree with the devices-backed path on both
     plain resolution and divisibility-driven axis dropping."""
-    am = jax.sharding.AbstractMesh((("data", 4), ("model", 4)))
+    am = jax.sharding.AbstractMesh((4, 4), ("data", "model"))
     ctx = MeshContext(mesh=am, rules=dict(DEFAULT_RULES))
     for axes in [("batch", "seq", "embed"), ("embed_fsdp", "mlp"),
                  ("vocab", "embed")]:

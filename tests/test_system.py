@@ -135,7 +135,9 @@ MINI_DRYRUN = textwrap.dedent("""
     from repro.launch.dryrun import lower_cell
     from repro.utils.hlo_analysis import collective_bytes
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     out = {}
     for arch in ["yi-9b", "grok-1-314b", "mamba2-130m", "jamba-1.5-large-398b"]:
         cfg = get_smoke_config(arch)

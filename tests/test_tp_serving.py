@@ -242,7 +242,7 @@ def test_abstract_quantized_tree_is_sharded_int8(arch, overrides, tp):
     cfg = get_config(arch)
     rules = dict(cfg.rules())
     rules.update(overrides)
-    mesh = jax.sharding.AbstractMesh((("model", tp),))
+    mesh = jax.sharding.AbstractMesh((tp,), ("model",))
     tree = abstract_quantized_params(model_specs(cfg), mesh, rules)
     leaves = jax.tree.leaves(tree)
     assert all(l.sharding is not None for l in leaves)
@@ -317,7 +317,7 @@ def test_grok_overrides_merge_over_default_rules():
     rules = cfg.rules()
     assert rules["experts"] is None          # 8 experts on a 16-way axis
     assert rules["expert_mlp"] == "model"    # TP the expert FFN dim instead
-    mesh = jax.sharding.AbstractMesh((("model", 16),))
+    mesh = jax.sharding.AbstractMesh((16,), ("model",))
     with use_mesh(mesh, rules) as ctx:
         assert ctx.rules["expert_mlp"] == "model"      # override applied
         assert ctx.rules["experts"] is None
@@ -344,7 +344,7 @@ def test_mesh_active_inside_context_only():
 def test_abstract_mesh_resolution_matches_real_mesh():
     """MeshContext.resolve reads sizes from AbstractMesh.shape — the
     residency math must agree with a real mesh of the same shape."""
-    am = jax.sharding.AbstractMesh((("model", 1),))
+    am = jax.sharding.AbstractMesh((1,), ("model",))
     rm = make_serving_mesh(tp=1)
     a = MeshContext(mesh=am, rules=dict(DEFAULT_RULES))
     r = MeshContext(mesh=rm, rules=dict(DEFAULT_RULES))
