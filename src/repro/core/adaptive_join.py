@@ -100,7 +100,6 @@ def adaptive_join(
     metrics = registry_of(client)
     if metrics is not None:
         metrics.counter("join_adaptive_runs").inc()
-    t0 = trace.now() if trace else 0.0
     stats = (stats if stats is not None
              else generate_statistics(r1, r2, j, counter=client.count_tokens))
     if prefix_cached is None:
@@ -117,43 +116,45 @@ def adaptive_join(
     )
     rounds = 0
     schedule = []
-    while True:
-        rounds += 1
-        if rounds > max_rounds:
-            raise RuntimeError(
-                f"adaptive join did not converge after {max_rounds} rounds"
-            )
-        eff_e = min(e, 1.0)  # selectivity can never exceed 1
-        b1, b2 = optimal_batch_sizes(stats, eff_e, t, headroom=stats.s3 + 1,
-                                     prefix_cached=prefix_cached)
-        schedule.append({"round": rounds, "estimate": eff_e, "b1": b1, "b2": b2})
-        if trace:
-            trace.instant("adaptive_round", "join", round=rounds,
-                          estimate=eff_e, b1=b1, b2=b2)
-        if metrics is not None:
-            metrics.counter("join_adaptive_rounds").inc()
-        try:
-            result = block_join(
-                r1, r2, j, client, b1, b2,
-                completed=completed if resume else None,
-                ledger=ledger,
-            )
-            result.meta.update({
-                "operator": "adaptive",
-                "rounds": rounds,
-                "final_estimate": eff_e,
-                "schedule": schedule,
-                "resume": resume,
-                "prefix_cached": prefix_cached,
-            })
+    with trace.span("join.adaptive", "join") as sp:
+        while True:
+            rounds += 1
+            if rounds > max_rounds:
+                raise RuntimeError(f"adaptive join did not converge after "
+                                   f"{max_rounds} rounds")
+            eff_e = min(e, 1.0)  # selectivity can never exceed 1
+            b1, b2 = optimal_batch_sizes(stats, eff_e, t,
+                                         headroom=stats.s3 + 1,
+                                         prefix_cached=prefix_cached)
+            schedule.append({"round": rounds, "estimate": eff_e,
+                             "b1": b1, "b2": b2})
             if trace:
-                trace.complete("join.adaptive", "join", t0, rounds=rounds,
-                               pairs=len(result.pairs),
-                               degraded=int(bool(result.meta.get("degraded"))))
-            return result
-        except Overflow:
-            if eff_e >= 1.0 and (b1, b2) == (1, 1):
-                # Cannot shrink further: a single pair's answer exceeds the
-                # window — data/task infeasible under this context limit.
-                raise
-            e = eff_e * alpha
+                trace.instant("adaptive_round", "join", round=rounds,
+                              estimate=eff_e, b1=b1, b2=b2)
+            if metrics is not None:
+                metrics.counter("join_adaptive_rounds").inc()
+            try:
+                result = block_join(
+                    r1, r2, j, client, b1, b2,
+                    completed=completed if resume else None,
+                    ledger=ledger,
+                )
+                result.meta.update({
+                    "operator": "adaptive",
+                    "rounds": rounds,
+                    "final_estimate": eff_e,
+                    "schedule": schedule,
+                    "resume": resume,
+                    "prefix_cached": prefix_cached,
+                })
+                if sp is not None:
+                    sp.update(rounds=rounds, pairs=len(result.pairs),
+                              degraded=int(bool(result.meta.get("degraded"))))
+                return result
+            except Overflow:
+                if eff_e >= 1.0 and (b1, b2) == (1, 1):
+                    # Cannot shrink further: a single pair's answer exceeds
+                    # the window — data/task infeasible under this context
+                    # limit.
+                    raise
+                e = eff_e * alpha

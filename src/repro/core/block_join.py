@@ -131,8 +131,8 @@ def block_join(
         if not _covered(slices1[i] + slices2[k], completed)
     ]
 
-    t0 = trace.now() if trace else 0.0
-    with Timer() as timer:
+    with trace.span("join.block", "join", b1=b1, b2=b2,
+                    blocks=len(work)) as sp, Timer() as timer:
         prompts: List[Tuple[Tuple[int, int], str, int]] = []
         for (i, k) in work:
             lo1, hi1 = slices1[i]
@@ -215,16 +215,13 @@ def block_join(
             cancel_unfinished(client, handles)
             raise
         if overflowed and degraded is None:
-            if trace:
-                trace.complete("join.block", "join", t0, b1=b1, b2=b2,
-                               blocks=len(work), outcome="overflow")
+            if sp is not None:
+                sp["outcome"] = "overflow"
             raise Overflow(ledger, partial=pairs)
+        if sp is not None:
+            sp["outcome"] = "degraded" if degraded is not None else "ok"
+            sp["pairs"] = len(pairs)
 
-    if trace:
-        trace.complete(
-            "join.block", "join", t0, b1=b1, b2=b2, blocks=len(work),
-            outcome="degraded" if degraded is not None else "ok",
-            pairs=len(pairs))
     meta = {"operator": "block", "b1": b1, "b2": b2, "calls": ledger.calls,
             "out_of_range_pairs": out_of_range,
             "dropped_segments": dropped_segments}

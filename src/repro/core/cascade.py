@@ -160,12 +160,11 @@ def cascade_tuple_join(
     metrics = registry_of(small) or registry_of(large)
     if metrics is not None:
         metrics.counter("join_cascade_runs").inc()
-    t0 = trace.now() if trace else 0.0
     small_ledger = Ledger()
     large_ledger = Ledger()
     degraded: Optional[BackendUnavailable] = None
     escalated: Sequence[Tuple[int, int]] = []
-    with Timer() as timer:
+    with trace.span("join.cascade", "join") as sp, Timer() as timer:
         try:
             scores = score_pairs(index, r1, r2, j, small, small_ledger,
                                  window=window)
@@ -191,11 +190,10 @@ def cascade_tuple_join(
                 except BackendUnavailable as exc:
                     scores.update(exc.partial or {})
                     degraded = exc
-    pairs = {p for p, (dec, _) in scores.items() if dec}
-    if trace:
-        trace.complete("join.cascade", "join", t0, pairs_total=len(index),
-                       escalated=len(escalated), matches=len(pairs),
-                       degraded=int(degraded is not None))
+        pairs = {p for p, (dec, _) in scores.items() if dec}
+        if sp is not None:
+            sp.update(pairs_total=len(index), escalated=len(escalated),
+                      matches=len(pairs), degraded=int(degraded is not None))
     meta = {
         "operator": "cascade_tuple",
         "threshold": threshold,

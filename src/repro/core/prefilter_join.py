@@ -190,11 +190,10 @@ def prefilter_join(
     metrics = registry_of(client)
     if metrics is not None:
         metrics.counter("join_prefilter_runs").inc()
-    t0 = trace.now() if trace else 0.0
     ledger = Ledger()
     large_ledger = Ledger()
     escalated: List[Pair] = []
-    with Timer() as timer:
+    with trace.span("join.prefilter", "join", k=k) as sp, Timer() as timer:
         # one embedding call per table, input tokens only (cost model's
         # embedding-API accounting)
         before = embedder.tokens_read
@@ -236,11 +235,10 @@ def prefilter_join(
             pairs = _decide_pairs_decode(
                 candidates, r1, r2, j, client, ledger,
                 window=window, max_answer_tokens=max_answer_tokens)
+        if sp is not None:
+            sp.update(candidates=len(candidates), matches=len(pairs),
+                      escalated=len(escalated))
     cross = len(r1) * len(r2)
-    if trace:
-        trace.complete("join.prefilter", "join", t0, k=k,
-                       candidates=len(candidates), matches=len(pairs),
-                       escalated=len(escalated))
     return JoinResult(
         pairs=pairs,
         ledger=ledger + large_ledger if large is not None else ledger,

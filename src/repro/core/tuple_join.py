@@ -65,14 +65,13 @@ def tuple_join(
     metrics = registry_of(client)
     if metrics is not None:
         metrics.counter("join_tuple_runs").inc()
-    t0 = trace.now() if trace else 0.0
     ledger = Ledger()
     pairs = set()
     decided = set()
     all_pairs = [(i, k) for i in range(len(r1)) for k in range(len(r2))]
     index = iter(all_pairs)
     degraded: Optional[BackendUnavailable] = None
-    with Timer() as timer:
+    with trace.span("join.tuple", "join") as sp, Timer() as timer:
         while degraded is None:
             chunk = list(itertools.islice(index, window))
             if not chunk:
@@ -107,10 +106,9 @@ def tuple_join(
             except Exception:
                 cancel_unfinished(client, handles)
                 raise
-    if trace:
-        trace.complete("join.tuple", "join", t0, pairs_checked=len(decided),
-                       matches=len(pairs),
-                       degraded=int(degraded is not None))
+        if sp is not None:
+            sp.update(pairs_checked=len(decided), matches=len(pairs),
+                      degraded=int(degraded is not None))
     meta = {"operator": "tuple"}
     if degraded is not None:
         meta.update({
@@ -135,21 +133,19 @@ def _tuple_join_scored(
     metrics = registry_of(client)
     if metrics is not None:
         metrics.counter("join_tuple_scored_runs").inc()
-    t0 = trace.now() if trace else 0.0
     ledger = Ledger()
     degraded: Optional[BackendUnavailable] = None
-    with Timer() as timer:
+    with trace.span("join.tuple", "join", scoring=1) as sp, Timer() as timer:
         try:
             scores = score_pairs(index, r1, r2, j, client, ledger,
                                  window=window)
         except BackendUnavailable as exc:
             scores = dict(exc.partial or {})
             degraded = exc
-    pairs = {p for p, (dec, _) in scores.items() if dec}
-    if trace:
-        trace.complete("join.tuple", "join", t0, scoring=1,
-                       pairs_checked=len(scores), matches=len(pairs),
-                       degraded=int(degraded is not None))
+        pairs = {p for p, (dec, _) in scores.items() if dec}
+        if sp is not None:
+            sp.update(pairs_checked=len(scores), matches=len(pairs),
+                      degraded=int(degraded is not None))
     meta = {"operator": "tuple", "scoring": True}
     if degraded is not None:
         meta.update({

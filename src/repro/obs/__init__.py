@@ -12,8 +12,12 @@ Three pieces, layered so the hot path stays cheap:
 * :mod:`repro.obs.metrics` — always-on counters / gauges / streaming
   histograms with fixed log-spaced buckets, mergeable across replicas
   (and replica incarnations) exactly like ``Ledger.__add__``.
-* :mod:`repro.obs.export` — Perfetto/Chrome ``trace_event`` JSON and a
-  Prometheus-style text snapshot.
+* :mod:`repro.obs.export` — Perfetto/Chrome ``trace_event`` JSON and
+  counter-track timelines.
+
+A live recorder's synchronous spans also enter ``jax.profiler``
+annotations of the same name, so a profiler capture shows them on the
+device trace's clock (jax is imported on the first live span only).
 """
 
 from repro.obs.trace import (NULL_TRACE, NullRecorder, TraceRecorder,
@@ -21,8 +25,7 @@ from repro.obs.trace import (NULL_TRACE, NullRecorder, TraceRecorder,
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                registry_of)
 from repro.obs.export import (chrome_trace_events, chrome_trace_json,
-                              prometheus_text, queue_depth_timeline,
-                              write_chrome_trace)
+                              queue_depth_timeline, write_chrome_trace)
 
 __all__ = [
     "Counter",
@@ -35,7 +38,6 @@ __all__ = [
     "TraceRecorder",
     "chrome_trace_events",
     "chrome_trace_json",
-    "prometheus_text",
     "queue_depth_timeline",
     "recorder_from_env",
     "registry_of",

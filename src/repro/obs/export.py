@@ -1,4 +1,4 @@
-"""Trace + metrics exporters: Perfetto/Chrome JSON, Prometheus text.
+"""Trace exporters: Perfetto/Chrome JSON and counter-track timelines.
 
 The Chrome ``trace_event`` format (the JSON array Perfetto and
 ``chrome://tracing`` both load) maps directly onto the recorder's event
@@ -15,7 +15,6 @@ from __future__ import annotations
 import json
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Event
 
 #: pid → human label shown by Perfetto's process track headers; pids are
@@ -92,48 +91,10 @@ def queue_depth_timeline(events: Sequence[Event], name: str = "queue_depth",
     return [pts[int(i * step)] for i in range(max_points)]
 
 
-def _fmt(v: float) -> str:
-    return repr(int(v)) if float(v).is_integer() else repr(float(v))
-
-
-def prometheus_text(registry: MetricsRegistry, prefix: str = "repro") -> str:
-    """Prometheus exposition-format snapshot of a registry.
-
-    Counters render as ``<prefix>_<name>_total``, gauges as value +
-    ``_peak``, histograms as the conventional cumulative ``_bucket``
-    series with ``le`` labels plus ``_sum`` / ``_count``.
-    """
-    snap = registry.snapshot()
-    lines: List[str] = []
-    for name, value in snap["counters"].items():
-        full = f"{prefix}_{name}"
-        lines.append(f"# TYPE {full} counter")
-        lines.append(f"{full}_total {_fmt(value)}")
-    for name, g in snap["gauges"].items():
-        full = f"{prefix}_{name}"
-        lines.append(f"# TYPE {full} gauge")
-        lines.append(f"{full} {_fmt(g['value'])}")
-        lines.append(f"{full}_peak {_fmt(g['peak'])}")
-    for name in snap["histograms"]:
-        hist = registry.get(name)
-        full = f"{prefix}_{name}"
-        lines.append(f"# TYPE {full} histogram")
-        cum = 0
-        for i, edge in enumerate(hist.bounds):
-            cum += hist.counts[i]
-            if hist.counts[i]:
-                lines.append(f'{full}_bucket{{le="{edge:.6g}"}} {cum}')
-        lines.append(f'{full}_bucket{{le="+Inf"}} {hist.count}')
-        lines.append(f"{full}_sum {_fmt(hist.total)}")
-        lines.append(f"{full}_count {hist.count}")
-    return "\n".join(lines) + "\n"
-
-
 __all__ = [
     "CLUSTER_PID",
     "chrome_trace_events",
     "chrome_trace_json",
-    "prometheus_text",
     "queue_depth_timeline",
     "write_chrome_trace",
 ]

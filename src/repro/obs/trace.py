@@ -3,8 +3,20 @@
 The recorder is deliberately dumb: an event is one tuple appended to a
 ``collections.deque(maxlen=capacity)`` under one short lock.  No string
 formatting, no I/O, no allocation beyond the tuple and its args dict —
-rendering (Chrome ``trace_event`` JSON, Prometheus text) happens at
-export time in :mod:`repro.obs.export`.
+rendering (Chrome ``trace_event`` JSON) happens at export time in
+:mod:`repro.obs.export`.
+
+Profiler trace
+--------------
+A live recorder's :meth:`TraceRecorder.span` also enters a
+``jax.profiler.TraceAnnotation`` of the same name (and the args given at
+entry), so every synchronous span sits on the device trace's clock in a
+``jax.profiler`` capture: each device program lies under the host span
+that launched it.  Without a running profiler the annotation is a no-op.
+Spans whose start and end fall on different threads (the ``request``
+lifecycle, submit → retire) are recorded with :meth:`complete` and stay
+in the ring only.  jax is imported on the first live span, so importing
+:mod:`repro.obs` stays jax-free.
 
 Clock discipline
 ----------------
@@ -25,7 +37,9 @@ Instrumentation sites guard the *argument construction* too::
         self.trace.instant("retry", "executor", request=h.request_id)
 
 so a disabled recorder costs one attribute load and one branch per
-site.  ``REPRO_TRACE=1`` (or any non-empty, non-"0" value) flips
+site.  A ``with trace.span(...)`` site costs, when tracing is off, one
+call that returns the same shared ``contextlib.nullcontext`` every time
+(no context object is built per call, and no jax call is made).  ``REPRO_TRACE=1`` (or any non-empty, non-"0" value) flips
 :func:`recorder_from_env` to a live recorder.  Tracing is strictly
 observational: it never touches tokens, compute, or control flow, so
 every traced configuration is token-identical to the untraced one.
@@ -87,9 +101,9 @@ class NullRecorder:
     def counter(self, name, value, *, cat="serve", pid=0, **extra) -> None:
         pass
 
-    @contextlib.contextmanager
     def span(self, name, cat="serve", *, pid=0, tid=0, **args):
-        yield
+        """The shared no-op context; ``as`` binds None."""
+        return _NULL_SPAN
 
     def events(self) -> List[Event]:
         return []
@@ -111,6 +125,9 @@ class NullRecorder:
 
 #: shared no-op singleton — safe because it holds no state
 NULL_TRACE = NullRecorder()
+
+#: what a disabled recorder's span() returns, every call
+_NULL_SPAN = contextlib.nullcontext()
 
 
 class TraceRecorder:
@@ -170,10 +187,18 @@ class TraceRecorder:
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "serve", *, pid: int = 0,
              tid: int = 0, **args):
-        """Context-manager sugar over :meth:`now` + :meth:`complete`."""
+        """A complete span around the ``with`` body, entered on one
+        thread: recorded in the ring (:meth:`now` + :meth:`complete`)
+        and, under the same name, as a ``jax.profiler.TraceAnnotation``
+        carrying ``args``.  ``as`` binds the event's args dict: keys set
+        on it before the body ends are recorded in the ring too (the
+        annotation keeps the entry args)."""
+        from jax.profiler import TraceAnnotation  # jax-free until here
+
         start = self.clock.now()
         try:
-            yield
+            with TraceAnnotation(name, **args):
+                yield args
         finally:
             self.complete(name, cat, start, pid=pid, tid=tid, **args)
 

@@ -59,6 +59,7 @@ the two levels never form a cycle.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -495,7 +496,7 @@ class Cluster:
                         "cluster has no live replicas") from self._fatal
                 idx = self.router.pick(key, cost, view)
             rep = self._replicas[idx]
-            with rep.lock:
+            with self._submit_lock(rep):
                 if not rep.alive:
                     continue  # failure raced the routing decision
                 if ch.score is not None:
@@ -512,6 +513,27 @@ class Cluster:
             with self._mu:
                 self._work.notify_all()
             return
+
+    @contextlib.contextmanager
+    def _submit_lock(self, rep: _Replica):
+        """``rep.lock`` for the submit and cancel paths, which wait here
+        while the replica's worker holds it through an engine step.  Each
+        acquisition and the seconds waited for it (``perf_counter``) are
+        booked into the replica's ``ExecutorStats`` once held; with a
+        live recorder the wait is a ``cluster.lock_wait`` span on the
+        caller's thread."""
+        with self.trace.span("cluster.lock_wait", "cluster",
+                             pid=CLUSTER_PID, replica=rep.idx):
+            t0 = time.perf_counter()
+            rep.lock.acquire()
+            waited = time.perf_counter() - t0
+        try:
+            stats = rep.executor.stats
+            stats.submit_lock_waits += 1
+            stats.submit_lock_wait_s += waited
+            yield
+        finally:
+            rep.lock.release()
 
     def hold(self) -> None:
         """Gang submission: buffer routed requests without executing.
@@ -552,7 +574,7 @@ class Cluster:
             rep = self._replicas[ch.replica] if ch.replica >= 0 else None
             if rep is None:
                 return False
-            with rep.lock:
+            with self._submit_lock(rep):
                 serve = ch._serve
                 if (serve is None
                         or rep.handles.get(serve.request_id) is not ch):
@@ -569,7 +591,7 @@ class Cluster:
                     # a hedged straggler lives on two replicas — kill
                     # the duplicate too, or it would finish as waste
                     hrep = self._replicas[ch.hedge_replica]
-                    with hrep.lock:
+                    with self._submit_lock(hrep):
                         hrep.handles.pop(twin.request_id, None)
                         if hrep.alive and not twin.done():
                             hrep.executor.cancel(twin)
@@ -671,7 +693,9 @@ class Cluster:
                 if not rep.alive:
                     return
                 try:
-                    finished = rep.executor.step()
+                    with self.trace.span("cluster.step", "cluster",
+                                         pid=rep.idx):
+                        finished = rep.executor.step()
                 except Exception as exc:  # retries exhausted
                     failure = exc
                     finished = []

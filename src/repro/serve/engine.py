@@ -47,6 +47,7 @@ The paper's block-join prompts run through *this* (via
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Any, List, Optional, Sequence, Tuple
 
@@ -369,6 +370,11 @@ class Engine:
         #: required working set, as opposed to pool.peak_pages which also
         #: counts elastic (evictable) prefix-cache retention
         self._peak_live_pages = 0
+        #: positions the prefill programs were launched over — rows ×
+        #: bucket, padding rows included (init_state's warm prefill and
+        #: score batches too), counted at each launch and never backed
+        #: out; the executor books its deltas into ExecutorStats
+        self.prefill_positions_run = 0
 
         if self.paged:
             # ONE pool backs live decode state and the prefix cache; +1
@@ -398,7 +404,8 @@ class Engine:
         self._prefill = self._mjit(
             lambda p, toks, vlen: prefill(
                 cfg, p, {"tokens": toks}, max_seq=self.max_seq, valid_len=vlen
-            )
+            ),
+            name="engine_prefill",
         )
         # paged prefill: no max_seq padding — K/V come back bucket-length
         # and are page-scattered into the pool (shape-specialized per
@@ -406,20 +413,23 @@ class Engine:
         self._prefill_bucket = self._mjit(
             lambda p, toks, vlen: prefill(
                 cfg, p, {"tokens": toks}, max_seq=toks.shape[1], valid_len=vlen
-            )
+            ),
+            name="engine_prefill_bucket",
         )
         self._chunked_prefill = self._mjit(
             lambda p, toks, vlen, kp, vp, plen: chunked_prefill(
                 cfg, p, {"tokens": toks}, max_seq=self.max_seq,
                 valid_len=vlen, prefix_k=kp, prefix_v=vp, prefix_len=plen,
-            )
+            ),
+            name="engine_chunked_prefill",
         )
         self._chunked_prefill_paged = self._mjit(
             lambda p, toks, vlen, kp, vp, plen: chunked_prefill(
                 cfg, p, {"tokens": toks}, max_seq=self.max_seq,
                 valid_len=vlen, prefix_k=kp, prefix_v=vp, prefix_len=plen,
                 paged=True,
-            )
+            ),
+            name="engine_chunked_prefill_paged",
         )
         # scoring variants (DESIGN.md §13): identical passes that unembed
         # every position — score_rows reads teacher-forced continuation
@@ -431,21 +441,24 @@ class Engine:
             lambda p, toks, vlen: prefill(
                 cfg, p, {"tokens": toks}, max_seq=toks.shape[1],
                 valid_len=vlen, all_logits=True,
-            )
+            ),
+            name="engine_prefill_bucket_all",
         )
         self._chunked_prefill_all = self._mjit(
             lambda p, toks, vlen, kp, vp, plen: chunked_prefill(
                 cfg, p, {"tokens": toks}, max_seq=self.max_seq,
                 valid_len=vlen, prefix_k=kp, prefix_v=vp, prefix_len=plen,
                 all_logits=True,
-            )
+            ),
+            name="engine_chunked_prefill_all",
         )
         self._chunked_prefill_all_paged = self._mjit(
             lambda p, toks, vlen, kp, vp, plen: chunked_prefill(
                 cfg, p, {"tokens": toks}, max_seq=self.max_seq,
                 valid_len=vlen, prefix_k=kp, prefix_v=vp, prefix_len=plen,
                 paged=True, all_logits=True,
-            )
+            ),
+            name="engine_chunked_prefill_all_paged",
         )
         # per-position log-prob gather: select each row's continuation
         # -predicting positions, log-softmax, take the target token ids
@@ -454,7 +467,8 @@ class Engine:
                 jax.nn.log_softmax(
                     jnp.take_along_axis(lg, idx[:, :, None], axis=1),
                     axis=-1),
-                tgt[:, :, None], axis=2)[..., 0])
+                tgt[:, :, None], axis=2)[..., 0],
+            name="engine_score_gather")
         # embedding surface (DESIGN.md §14): the same bucketed ragged
         # batch shape as prefill, but no KV cache and no unembed — the
         # backbone's final-norm hidden states come back mean-pooled per
@@ -463,10 +477,13 @@ class Engine:
         self._encode = self._mjit(
             lambda p, toks, vlen: encode(
                 cfg, p, {"tokens": toks}, valid_len=vlen
-            )
+            ),
+            name="engine_encode",
         )
         self._decode = self._mjit(
-            lambda p, cache, toks, act: decode_step(cfg, p, cache, toks, active=act)
+            lambda p, cache, toks, act: decode_step(cfg, p, cache, toks,
+                                                    active=act),
+            name="engine_decode",
         )
         # paged decode donates the cache tree: the page pool (GiB-scale
         # at real configs) must be appended to in place, not copied per
@@ -475,21 +492,25 @@ class Engine:
             lambda p, cache, toks, act: decode_step(cfg, p, cache, toks,
                                                     active=act),
             donate_argnums=(1,),
+            name="engine_decode_paged",
         )
         # speculative verification (DESIGN.md §11): one model call scores
         # a spec_k+1-token window per slot; the paged variant donates the
         # pool exactly like _decode_paged
         self._verify = self._mjit(
-            lambda p, cache, toks: verify_step(cfg, p, cache, toks))
+            lambda p, cache, toks: verify_step(cfg, p, cache, toks),
+            name="engine_verify")
         self._verify_paged = self._mjit(
             lambda p, cache, toks: verify_step(cfg, p, cache, toks),
             donate_argnums=(1,),
+            name="engine_verify_paged",
         )
         # post-verify logits select: row r keeps the logits of its last
         # accepted window position (counts[r]-1)
         self._select_logits = self._mjit(
             lambda lg, sel: jnp.take_along_axis(
-                lg, sel[:, None, None], axis=1)[:, 0])
+                lg, sel[:, None, None], axis=1)[:, 0],
+            name="engine_select_logits")
         # Per-leaf batch axis of the cache tree, derived from the logical
         # axis names in cache_specs — k/v carry batch at axis 1, the hybrid
         # conv/ssm states at axis 2, "len" at axis 0.
@@ -498,16 +519,24 @@ class Engine:
             cache_specs(cfg, slots, max_seq),
             is_leaf=is_spec,
         )
-        self._insert = self._mjit(self._insert_impl, donate_argnums=(0, 1))
+        self._insert = self._mjit(self._insert_impl, donate_argnums=(0, 1),
+                                  name="engine_insert")
         self._insert_logits = self._mjit(
             lambda dst, src, row, slot: dst.at[slot].set(src[row]),
             donate_argnums=(0,),
+            name="engine_insert_logits",
         )
         self._default_executor = None  # lazy, for the generate() facade
 
     # ------------------------------------------------------------------
-    def _mjit(self, fn, **jit_kwargs):
-        """``jax.jit`` + this replica's mesh context.
+    def _mjit(self, fn, *, name: str, **jit_kwargs):
+        """``jax.jit`` of ``fn`` under the program name ``name`` + this
+        replica's mesh context.
+
+        ``name`` is what profiles show: ``jit_<name>`` on the TPU's
+        ``XLA Modules`` line, ``PjitFunction(<name>)`` on the host — the
+        engine's programs read ``engine_*`` instead of ``jit__lambda``.
+        It changes the program's name only, not its code.
 
         Without a mesh this IS ``jax.jit`` — byte-for-byte the old
         engine.  With one, every call runs under ``use_mesh(self.mesh,
@@ -521,7 +550,9 @@ class Engine:
         explicit in/out shardings are needed: GSPMD propagates from
         committed operands (donated caches keep their layout).
         """
-        jf = jax.jit(fn, **jit_kwargs)
+        named = functools.partial(fn)
+        named.__name__ = named.__qualname__ = name
+        jf = jax.jit(named, **jit_kwargs)
         if self.mesh is None:
             return jf
         mesh, rules = self.mesh, self.rules
@@ -660,6 +691,7 @@ class Engine:
         toks = jnp.zeros((B, L), jnp.int32)
         vlen = jnp.ones((B,), jnp.int32)
         cache, logits = self._prefill(self.params, toks, vlen)
+        self.prefill_positions_run += B * L
         return DecodeState(cache=cache, logits=logits)
 
     def prefill_rows(
@@ -693,17 +725,15 @@ class Engine:
             raise ValueError(
                 f"prompt of {max(lens)} tokens exceeds engine max_seq {self.max_seq}"
             )
-        t0 = self.trace.now() if self.trace else 0.0
-        if self.paged:
-            out = self._prefill_rows_paged(ids, lens)
-        else:
-            out = self._prefill_rows_dense(ids, lens)
-        if self.trace:
-            self.trace.complete(
-                "engine.prefill", "engine", t0, pid=self.trace_pid,
-                rows=len(prompts),
-                bucket=int(_bucket(max(lens), self.prefill_buckets)),
-                cached=int(sum(out[3])))
+        with self.trace.span("engine.prefill", "engine", pid=self.trace_pid,
+                             rows=len(prompts)) as sp:
+            if self.paged:
+                out = self._prefill_rows_paged(ids, lens)
+            else:
+                out = self._prefill_rows_dense(ids, lens)
+            if sp is not None:
+                sp["bucket"] = int(_bucket(max(lens), self.prefill_buckets))
+                sp["cached"] = int(sum(out[3]))
         return out
 
     def score_rows(
@@ -740,44 +770,44 @@ class Engine:
                 f"prompt+continuation of {max(lens)} tokens exceeds "
                 f"engine max_seq {self.max_seq}")
         limits = [len(p) - 1 for p in prompt_ids]
-        t0 = self.trace.now() if self.trace else 0.0
-        if self.paged:
-            cache, logits, _, cached = self._prefill_rows_paged(
-                seqs, lens, limits=limits, all_logits=True)
-        else:
-            cache, logits, _, cached = self._prefill_rows_dense(
-                seqs, lens, limits=limits, all_logits=True)
-        # logits: (slots, L, vocab) over each row's *computed* suffix —
-        # continuation token i lives at suffix-relative position
-        # len(prompt_ids) - 1 + i - cached[r]
-        M = max(len(ci) for ci in cont_ids)
-        idx = np.zeros((self.slots, M), np.int32)
-        tgt = np.zeros((self.slots, M), np.int32)
-        for r, (pi, ci) in enumerate(zip(prompt_ids, cont_ids)):
-            base = len(pi) - 1 - cached[r]
-            for i, t in enumerate(ci):
-                idx[r, i] = base + i
-                tgt[r, i] = t
-        lp = np.asarray(self._score_gather(
-            logits, jnp.asarray(idx), jnp.asarray(tgt)))
-        rows = []
-        for r, (pi, ci) in enumerate(zip(prompt_ids, cont_ids)):
-            token_lps = [float(lp[r, i]) for i in range(len(ci))]
-            rows.append(ScoreRow(
-                logprob=float(sum(token_lps)), token_logprobs=token_lps,
-                prompt_tokens=len(pi), cont_tokens=len(ci),
-                cached_tokens=cached[r]))
-        if self.paged:
-            # release immediately: score rows never own pages past their
-            # batch — only the radix tree's own (evictable) refs remain
-            tables, _ = cache
-            for t in tables:
-                if t:
-                    self.pool.decref(t)
-        if self.trace:
-            self.trace.complete(
-                "engine.score", "engine", t0, pid=self.trace_pid,
-                rows=len(pairs), cached=int(sum(cached)))
+        with self.trace.span("engine.score", "engine", pid=self.trace_pid,
+                             rows=len(pairs)) as sp:
+            if self.paged:
+                cache, logits, _, cached = self._prefill_rows_paged(
+                    seqs, lens, limits=limits, all_logits=True)
+            else:
+                cache, logits, _, cached = self._prefill_rows_dense(
+                    seqs, lens, limits=limits, all_logits=True)
+            # logits: (slots, L, vocab) over each row's *computed*
+            # suffix — continuation token i lives at suffix-relative
+            # position len(prompt_ids) - 1 + i - cached[r]
+            M = max(len(ci) for ci in cont_ids)
+            idx = np.zeros((self.slots, M), np.int32)
+            tgt = np.zeros((self.slots, M), np.int32)
+            for r, (pi, ci) in enumerate(zip(prompt_ids, cont_ids)):
+                base = len(pi) - 1 - cached[r]
+                for i, t in enumerate(ci):
+                    idx[r, i] = base + i
+                    tgt[r, i] = t
+            lp = np.asarray(self._score_gather(
+                logits, jnp.asarray(idx), jnp.asarray(tgt)))
+            rows = []
+            for r, (pi, ci) in enumerate(zip(prompt_ids, cont_ids)):
+                token_lps = [float(lp[r, i]) for i in range(len(ci))]
+                rows.append(ScoreRow(
+                    logprob=float(sum(token_lps)), token_logprobs=token_lps,
+                    prompt_tokens=len(pi), cont_tokens=len(ci),
+                    cached_tokens=cached[r]))
+            if self.paged:
+                # release immediately: score rows never own pages past
+                # their batch — only the radix tree's own (evictable)
+                # refs remain
+                tables, _ = cache
+                for t in tables:
+                    if t:
+                        self.pool.decref(t)
+            if sp is not None:
+                sp["cached"] = int(sum(cached))
         return rows
 
     def embed_rows(
@@ -805,19 +835,16 @@ class Engine:
             raise ValueError(
                 f"text of {max(lens)} tokens exceeds engine max_seq "
                 f"{self.max_seq}")
-        t0 = self.trace.now() if self.trace else 0.0
         L = _bucket(max(lens), self.prefill_buckets)
-        toks = np.zeros((self.slots, L), np.int32)
-        vlen = np.zeros((self.slots,), np.int32)
-        for r, seq in enumerate(ids):
-            toks[r, :len(seq)] = seq
-            vlen[r] = len(seq)
-        vecs = np.asarray(self._encode(
-            self.params, jnp.asarray(toks), jnp.asarray(vlen)))
-        if self.trace:
-            self.trace.complete("engine.embed", "engine", t0,
-                                pid=self.trace_pid, rows=len(texts),
-                                bucket=int(L))
+        with self.trace.span("engine.embed", "engine", pid=self.trace_pid,
+                             rows=len(texts), bucket=int(L)):
+            toks = np.zeros((self.slots, L), np.int32)
+            vlen = np.zeros((self.slots,), np.int32)
+            for r, seq in enumerate(ids):
+                toks[r, :len(seq)] = seq
+                vlen[r] = len(seq)
+            vecs = np.asarray(self._encode(
+                self.params, jnp.asarray(toks), jnp.asarray(vlen)))
         return vecs[:len(texts)], lens
     def _prefill_rows_dense(self, ids: List[List[int]], lens: List[int],
                             limits: Optional[List[int]] = None,
@@ -854,6 +881,7 @@ class Engine:
                 cache, logits = fn(
                     self.params, jnp.asarray(toks), jnp.asarray(vlen)
                 )
+                self.prefill_positions_run += self.slots * L
             if pc is not None:
                 if not pc.pool.bound:
                     pc.pool.bind(cache["k"], cache["v"])
@@ -896,6 +924,7 @@ class Engine:
             plen[r] = m.length
             page_ids[r, : len(m.pages)] = m.pages
         kp, vp = pc.pool.gather(page_ids)
+        self.prefill_positions_run += self.slots * L
         if self.paged:
             fn = (self._chunked_prefill_all_paged if all_logits
                   else self._chunked_prefill_paged)
@@ -999,6 +1028,7 @@ class Engine:
                 cache, logits = fn(
                     self.params, jnp.asarray(toks), jnp.asarray(vlen)
                 )
+                self.prefill_positions_run += self.slots * L
             if not self.pool.bound:
                 self.pool.bind(cache["k"], cache["v"])
             self._scatter_rows(cache, chunks)

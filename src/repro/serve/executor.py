@@ -151,6 +151,19 @@ class ExecutorStats:
     #: score_e2e_s.count == requests_finished, exactly, on any replica
     #: merge (benchmarks/serving_latency.py asserts this)
     requests_finished: int = 0
+    #: positions the engine's prefill programs were launched over (rows ×
+    #: bucket, padding rows included; init_state's warm prefill and score
+    #: batches too) — what the device ran, so cancels and requeues never
+    #: back it out.  prefill_tokens_computed over this is the share of
+    #: prefill work that was prompt
+    prefill_positions_run: int = 0
+    #: cluster submit/cancel paths: acquisitions of this replica's lock
+    #: and the seconds spent waiting for them (booked by the Cluster)
+    submit_lock_waits: int = 0
+    submit_lock_wait_s: float = 0.0
+    #: submit → admit seconds on the executor's clock, summed over the
+    #: admissions ``refills`` counts (the queue_wait_s histogram's sum)
+    queued_s: float = 0.0
 
     @property
     def model_passes(self) -> int:
@@ -435,6 +448,7 @@ class ContinuousBatchingExecutor:
             self.trace.counter("outstanding_tokens", self.outstanding_tokens,
                                pid=self.trace_pid)
         expired = self._expire_deadlines()
+        launched = self.engine.prefill_positions_run
         try:
             finished = self._step_inner()
         except Exception:
@@ -444,6 +458,10 @@ class ContinuousBatchingExecutor:
                 raise
             self._backoff()
             return expired
+        finally:
+            # booked even when the step failed: the device did the work
+            self.stats.prefill_positions_run += (
+                self.engine.prefill_positions_run - launched)
         self._failstreak = 0
         if self._state is not None and not self.pending:
             # fully idle: release the dense slots × max_seq cache
@@ -532,31 +550,32 @@ class ContinuousBatchingExecutor:
             return finished
         if self.engine.spec_decode:
             return self._spec_step(occupied, finished)
-        # argmax + device→host sync only when some row actually samples
-        # (teacher-forced rows know their next token without the logits)
-        nxt = None
-        if any(h._forced is None for _, h in occupied):
-            nxt = np.asarray(jnp.argmax(self._state.logits, axis=-1), np.int32)
         tokens = np.zeros(self.engine.slots, np.int32)
         active = np.zeros(self.engine.slots, bool)
         eos = self.engine.tokenizer.eos_id
-        for slot, h in occupied:
-            tok = self._next_token(h, nxt, slot, eos)
-            if tok == eos:
-                self._retire(h, "stop", finished)
-                continue
-            if not self._emit(h, tok, finished):
-                continue
-            tokens[slot] = tok
-            active[slot] = True
+        with self.trace.span("executor.sample", "executor",
+                             pid=self.trace_pid, rows=len(occupied)):
+            # argmax + device→host sync only when some row actually
+            # samples (teacher-forced rows know their next token without
+            # the logits) — where the host waits on the device
+            nxt = None
+            if any(h._forced is None for _, h in occupied):
+                nxt = np.asarray(jnp.argmax(self._state.logits, axis=-1),
+                                 np.int32)
+            for slot, h in occupied:
+                tok = self._next_token(h, nxt, slot, eos)
+                if tok == eos:
+                    self._retire(h, "stop", finished)
+                    continue
+                if not self._emit(h, tok, finished):
+                    continue
+                tokens[slot] = tok
+                active[slot] = True
         if active.any():
-            t0 = self.trace.now() if self.trace else 0.0
-            self.engine.decode_active(self._state, tokens, active)
-            self.stats.decode_steps += 1
-            if self.trace:
-                self.trace.complete("decode_step", "executor", t0,
-                                    pid=self.trace_pid,
-                                    rows=int(active.sum()))
+            with self.trace.span("executor.decode_step", "executor",
+                                 pid=self.trace_pid, rows=int(active.sum())):
+                self.engine.decode_active(self._state, tokens, active)
+                self.stats.decode_steps += 1
         return finished
 
     def _spec_step(self, occupied, finished: List[ServeHandle]
@@ -568,42 +587,45 @@ class ContinuousBatchingExecutor:
         only, in order, exactly as sequential decode would."""
         eng = self.engine
         Kp = eng.spec_k + 1
-        nxt = None
-        if any(h._forced is None for _, h in occupied):
-            nxt = np.asarray(jnp.argmax(self._state.logits, axis=-1), np.int32)
         tokens = np.zeros((eng.slots, Kp), np.int32)
         n_tok = np.zeros(eng.slots, np.int32)
         active = np.zeros(eng.slots, bool)
         eos = eng.tokenizer.eos_id
-        for slot, h in occupied:
-            tok = self._next_token(h, nxt, slot, eos)
-            if tok == eos:
-                self._retire(h, "stop", finished)
-                continue
-            if not self._emit(h, tok, finished):
-                continue
-            # draft at most the remaining budget: tokens past it could
-            # never be emitted, so verifying them is pure waste
-            draft = eng.propose(h._spec_ctx, h._budget - h._emitted)
-            h._drafted += len(draft)
-            self.stats.drafted_tokens += len(draft)
-            tokens[slot, 0] = tok
-            tokens[slot, 1:1 + len(draft)] = draft
-            n_tok[slot] = 1 + len(draft)
-            active[slot] = True
+        with self.trace.span("executor.sample", "executor",
+                             pid=self.trace_pid, rows=len(occupied)):
+            nxt = None
+            if any(h._forced is None for _, h in occupied):
+                nxt = np.asarray(jnp.argmax(self._state.logits, axis=-1),
+                                 np.int32)
+            for slot, h in occupied:
+                tok = self._next_token(h, nxt, slot, eos)
+                if tok == eos:
+                    self._retire(h, "stop", finished)
+                    continue
+                if not self._emit(h, tok, finished):
+                    continue
+                # draft at most the remaining budget: tokens past it
+                # could never be emitted, so verifying them is pure waste
+                draft = eng.propose(h._spec_ctx, h._budget - h._emitted)
+                h._drafted += len(draft)
+                self.stats.drafted_tokens += len(draft)
+                tokens[slot, 0] = tok
+                tokens[slot, 1:1 + len(draft)] = draft
+                n_tok[slot] = 1 + len(draft)
+                active[slot] = True
         if not active.any():
             return finished
-        t0 = self.trace.now() if self.trace else 0.0
-        vlogits = eng.verify_active(self._state, tokens, n_tok, active)
-        self.stats.decode_steps += 1  # one model pass, however many tokens
-        if self.trace:
-            self.trace.complete("spec_verify", "executor", t0,
-                                pid=self.trace_pid,
-                                rows=int(active.sum()),
-                                drafted=int(n_tok.sum() - active.sum()))
+        with self.trace.span("executor.spec_verify", "executor",
+                             pid=self.trace_pid, rows=int(active.sum())) as sp:
+            vlogits = eng.verify_active(self._state, tokens, n_tok, active)
+            self.stats.decode_steps += 1  # one model pass, however many
+            if sp is not None:
+                sp["drafted"] = int(n_tok.sum() - active.sum())
         nxt2 = None
         if any(active[s] and h._forced is None for s, h in occupied):
-            nxt2 = np.asarray(jnp.argmax(vlogits, axis=-1), np.int32)
+            with self.trace.span("executor.sample", "executor",
+                                 pid=self.trace_pid, rows=int(active.sum())):
+                nxt2 = np.asarray(jnp.argmax(vlogits, axis=-1), np.int32)
         counts = np.zeros(eng.slots, np.int32)
         alive = np.zeros(eng.slots, bool)
         for slot, h in occupied:
@@ -795,25 +817,35 @@ class ContinuousBatchingExecutor:
         if not admitted:
             return
         admit_ts = self.clock.now()
-        qw = self.metrics.histogram("queue_wait_s")
-        for h in admitted:
-            qw.record(max(0.0, admit_ts - h._submit_ts))
-            if self.trace:
+        waits = [max(0.0, admit_ts - h._submit_ts) for h in admitted]
+        if self.trace:
+            for h in admitted:
                 self.trace.instant("admit", "request", pid=self.trace_pid,
                                    request=h.request_id, slot=h._slot)
+        with self.trace.span("executor.refill", "executor",
+                             pid=self.trace_pid, rows=len(admitted)):
+            self._prefill_admitted(admitted, waits, finished)
+
+    def _prefill_admitted(self, admitted: List[ServeHandle],
+                          waits: List[float],
+                          finished: List[ServeHandle]) -> None:
+        """Prefill the admitted requests as one batch and install each
+        row in its slot; book their queue waits once the prefill ran."""
         if self._state is None:
             self._state = self.engine.init_state()
-        t0 = self.trace.now() if self.trace else 0.0
-        cache, logits, lens, cached_lens = self.engine.prefill_rows(
-            [h.prompt for h in admitted])
+        with self.trace.span("executor.prefill", "executor",
+                             pid=self.trace_pid, rows=len(admitted)) as sp:
+            cache, logits, lens, cached_lens = self.engine.prefill_rows(
+                [h.prompt for h in admitted])
+            if sp is not None:
+                sp["computed"] = int(sum(lens) - sum(cached_lens))
+                sp["cached"] = int(sum(cached_lens))
         self.stats.prefill_batches += 1
         self.stats.refills += len(admitted)
-        if self.trace:
-            self.trace.complete(
-                "prefill", "executor", t0, pid=self.trace_pid,
-                rows=len(admitted),
-                computed=int(sum(lens) - sum(cached_lens)),
-                cached=int(sum(cached_lens)))
+        qw = self.metrics.histogram("queue_wait_s")
+        for w in waits:
+            qw.record(w)
+            self.stats.queued_s += w
         tok = self.engine.tokenizer
         for row, h in enumerate(admitted):
             h._cached_prompt = cached_lens[row]
@@ -878,9 +910,11 @@ class ContinuousBatchingExecutor:
                 self._queue.remove(h)
                 self._queued_tokens -= self._need(h)
                 h.status = ACTIVE
-            t0 = self.trace.now() if self.trace else 0.0
             try:
-                rows = eng.score_rows([(h.prompt, h.score) for h in batch])
+                with self.trace.span("executor.score_batch", "executor",
+                                     pid=self.trace_pid, rows=len(batch)):
+                    rows = eng.score_rows(
+                        [(h.prompt, h.score) for h in batch])
             except Exception:
                 # idempotent like generation prefill: back onto the queue
                 # front, count a retry, re-raise into step()'s handler
@@ -894,9 +928,6 @@ class ContinuousBatchingExecutor:
                 raise
             self.stats.prefill_batches += 1
             self.stats.score_requests += len(batch)
-            if self.trace:
-                self.trace.complete("score_batch", "executor", t0,
-                                    pid=self.trace_pid, rows=len(batch))
             done_ts = self.clock.now()
             se = self.metrics.histogram("score_e2e_s")
             for h, row in zip(batch, rows):

@@ -39,7 +39,6 @@ from repro.obs import (
     NullRecorder,
     TraceRecorder,
     chrome_trace_json,
-    prometheus_text,
     queue_depth_timeline,
     recorder_from_env,
     registry_of,
@@ -221,19 +220,6 @@ def test_registry_merge_and_kind_collision():
     snap = merged.snapshot()
     assert set(snap) == {"counters", "gauges", "histograms"}
     assert snap["counters"]["calls"] == 7
-
-
-def test_prometheus_text_renders_all_kinds():
-    r = MetricsRegistry()
-    r.counter("reqs").inc(2)
-    r.gauge("depth").set(4)
-    r.histogram("lat").record(0.01)
-    text = prometheus_text(r)
-    assert "repro_reqs_total 2" in text
-    assert "repro_depth 4" in text
-    assert "repro_depth_peak 4" in text
-    assert 'repro_lat_bucket{le="+Inf"} 1' in text
-    assert "repro_lat_count 1" in text
 
 
 # ---------------------------------------------------------------------------
