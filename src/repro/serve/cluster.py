@@ -1039,8 +1039,10 @@ class Cluster:
     # ------------------------------------------------------------------
     def stats(self) -> ExecutorStats:
         """Cluster-level throughput counters: the merge (field-wise sum)
-        of every replica's ExecutorStats."""
-        return sum((rep.executor.stats for rep in self._replicas),
+        of every replica's ExecutorStats, each copied by its executor's
+        ``read_stats``, so that no prefill is read half-booked while the
+        worker steps."""
+        return sum((rep.executor.read_stats() for rep in self._replicas),
                    ExecutorStats())
 
     def replica_stats(self) -> List[ExecutorStats]:

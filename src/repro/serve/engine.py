@@ -4,8 +4,10 @@ continuous batching with KV caches.
 The paper's block-join prompts run through *this* (via
 :class:`repro.serve.client.EngineClient`) when an architecture is hosted:
 
-* **Ragged batched prefill** — prompts right-padded to a bucket length;
-  causality + per-row ``valid_len`` make padding exact (see model.prefill).
+* **Ragged batched prefill** — prompts right-padded to a bucket length,
+  the batch to a row bucket (a power of two below ``slots``, or
+  ``slots``); causality + per-row ``valid_len`` make padding exact (see
+  model.prefill).
 * **Slot-refill continuous batching** — the engine exposes an incremental
   slot API (:meth:`init_state` / :meth:`prefill_rows` / :meth:`insert_row`
   / :meth:`decode_active`) driven by
@@ -231,6 +233,15 @@ class PagedDecodeState:
     table_np: np.ndarray       # (slots, max_pages) int32 mirror, dump-padded
 
 
+def _slots_wide(out: Tuple[Any, jax.Array],
+                slots: int) -> Tuple[Any, jax.Array]:
+    """A generation prefill's ``(cache, logits)`` with the logits padded
+    to ``slots`` rows: the decode batch's layout, whatever the row bucket
+    the prefill ran at."""
+    cache, logits = out
+    return cache, jnp.pad(logits, ((0, slots - logits.shape[0]), (0, 0)))
+
+
 def _bucket(n: int, buckets: Sequence[int]) -> int:
     for b in buckets:
         if n <= b:
@@ -354,6 +365,13 @@ class Engine:
             buckets = sorted({min(-(-b // pg) * pg, -(-max_seq // pg) * pg)
                               for b in buckets})
         self.prefill_buckets = buckets
+        # a refill's prefill batch is padded to a row bucket (the powers
+        # of two below slots, then slots), not to slots: a one-row refill
+        # computes one row
+        self.row_buckets = sorted({1 << i for i in range(slots.bit_length())
+                                   if 1 << i < slots} | {slots})
+        #: sequence buckets whose row buckets are compiled (_rehearse)
+        self._rehearsed: set = set()
         self._maxp = -(-max_seq // pg)  # page-table width per row
 
         # Radix-tree KV prefix cache (DESIGN.md §9): default-on for KV-only
@@ -370,10 +388,12 @@ class Engine:
         #: required working set, as opposed to pool.peak_pages which also
         #: counts elastic (evictable) prefix-cache retention
         self._peak_live_pages = 0
-        #: positions the prefill programs were launched over — rows ×
-        #: bucket, padding rows included (init_state's warm prefill and
-        #: score batches too), counted at each launch and never backed
-        #: out; the executor books its deltas into ExecutorStats
+        #: positions the prefill programs were launched over — row
+        #: bucket × bucket, pad rows included (init_state's warm prefill,
+        #: score batches and radix-hit launches, all slots rows, too),
+        #: counted at each launch and never backed out (the compile
+        #: rehearsal's launches are not counted); the executor books its
+        #: deltas into ExecutorStats
         self.prefill_positions_run = 0
 
         if self.paged:
@@ -401,34 +421,36 @@ class Engine:
             if 0 < b <= max_seq and b % pg == 0
         }) or [max_seq]
 
+        # generation prefills run at a row bucket and return slots-wide
+        # logits (_slots_wide), so installing a row is one program
         self._prefill = self._mjit(
-            lambda p, toks, vlen: prefill(
+            lambda p, toks, vlen: _slots_wide(prefill(
                 cfg, p, {"tokens": toks}, max_seq=self.max_seq, valid_len=vlen
-            ),
+            ), slots),
             name="engine_prefill",
         )
         # paged prefill: no max_seq padding — K/V come back bucket-length
         # and are page-scattered into the pool (shape-specialized per
         # bucket, exactly like the dense prefill)
         self._prefill_bucket = self._mjit(
-            lambda p, toks, vlen: prefill(
+            lambda p, toks, vlen: _slots_wide(prefill(
                 cfg, p, {"tokens": toks}, max_seq=toks.shape[1], valid_len=vlen
-            ),
+            ), slots),
             name="engine_prefill_bucket",
         )
         self._chunked_prefill = self._mjit(
-            lambda p, toks, vlen, kp, vp, plen: chunked_prefill(
+            lambda p, toks, vlen, kp, vp, plen: _slots_wide(chunked_prefill(
                 cfg, p, {"tokens": toks}, max_seq=self.max_seq,
                 valid_len=vlen, prefix_k=kp, prefix_v=vp, prefix_len=plen,
-            ),
+            ), slots),
             name="engine_chunked_prefill",
         )
         self._chunked_prefill_paged = self._mjit(
-            lambda p, toks, vlen, kp, vp, plen: chunked_prefill(
+            lambda p, toks, vlen, kp, vp, plen: _slots_wide(chunked_prefill(
                 cfg, p, {"tokens": toks}, max_seq=self.max_seq,
                 valid_len=vlen, prefix_k=kp, prefix_v=vp, prefix_len=plen,
                 paged=True,
-            ),
+            ), slots),
             name="engine_chunked_prefill_paged",
         )
         # scoring variants (DESIGN.md §13): identical passes that unembed
@@ -672,9 +694,10 @@ class Engine:
     def init_state(self):
         """Allocate the ``slots``-wide decode state.
 
-        Dense: run the real (jitted) prefill on an all-pad batch — a cache
-        with exactly the dtypes/shapes later row inserts will scatter
-        into, sharing its compilation with every future refill prefill.
+        Dense: run the real (jitted) prefill on an all-pad batch of
+        ``slots`` rows — a cache with exactly the dtypes/shapes later row
+        inserts will scatter into, sharing its compilation with every
+        later ``slots``-row refill prefill at the first bucket.
         Paged: no cache rows exist at all — just empty page tables and a
         zero logits buffer (DESIGN.md §10).
         """
@@ -699,13 +722,17 @@ class Engine:
     ) -> Tuple[Any, jax.Array, List[int], List[int]]:
         """Prefill up to ``slots`` prompts as one ragged batch.
 
-        The batch is padded to exactly ``slots`` rows so there is a single
-        compiled prefill per bucket length regardless of how many slots are
-        being refilled.  Returns ``(cache, logits, prompt_lens,
-        cached_lens)``; row ``r`` of the cache/logits belongs to
-        ``prompts[r]`` and is meant to be scattered into a free slot with
-        :meth:`insert_row`; ``cached_lens[r]`` prompt tokens were served
-        from the prefix cache instead of being computed.
+        The batch is padded to a row bucket (``row_buckets``: the
+        smallest power of two, or ``slots``, that holds the prompts), so
+        a one-row refill computes one row; a batch with a radix hit keeps
+        ``slots`` rows.  The first launch of whole prompts at a bucket
+        compiles every row bucket of it (:meth:`_rehearse`): later such
+        refills of any row count compile nothing.  Returns ``(cache,
+        logits, prompt_lens, cached_lens)``; row ``r`` of the cache and
+        of the slots-wide logits belongs to ``prompts[r]`` and is meant
+        to be scattered into a free slot with :meth:`insert_row`;
+        ``cached_lens[r]`` prompt tokens were served from the prefix
+        cache instead of being computed.
 
         With the prefix cache on, each prompt's token IDs are looked up in
         the radix tree first; the longest page-aligned cached prefix
@@ -732,6 +759,7 @@ class Engine:
             else:
                 out = self._prefill_rows_dense(ids, lens)
             if sp is not None:
+                sp["row_bucket"] = self._row_bucket(len(ids), any(out[3]))
                 sp["bucket"] = int(_bucket(max(lens), self.prefill_buckets))
                 sp["cached"] = int(sum(out[3]))
         return out
@@ -846,6 +874,7 @@ class Engine:
             vecs = np.asarray(self._encode(
                 self.params, jnp.asarray(toks), jnp.asarray(vlen)))
         return vecs[:len(texts)], lens
+
     def _prefill_rows_dense(self, ids: List[List[int]], lens: List[int],
                             limits: Optional[List[int]] = None,
                             all_logits: bool = False):
@@ -867,21 +896,7 @@ class Engine:
                     total_tokens=int(sum(lens)))
 
         try:
-            if any(cached):
-                cache, logits = self._prefill_over_cache(
-                    ids, matches, all_logits=all_logits)
-            else:
-                L = _bucket(max(lens), self.prefill_buckets)
-                toks = np.zeros((self.slots, L), np.int32)
-                vlen = np.ones((self.slots,), np.int32)  # pad rows: 1 dummy
-                for r, seq in enumerate(ids):
-                    toks[r, : len(seq)] = seq
-                    vlen[r] = len(seq)
-                fn = self._prefill_bucket_all if all_logits else self._prefill
-                cache, logits = fn(
-                    self.params, jnp.asarray(toks), jnp.asarray(vlen)
-                )
-                self.prefill_positions_run += self.slots * L
+            cache, logits = self._launch(ids, matches, cached, all_logits)
             if pc is not None:
                 if not pc.pool.bound:
                     pc.pool.bind(cache["k"], cache["v"])
@@ -898,43 +913,113 @@ class Engine:
                 m.release()
         return cache, logits, lens, cached
 
-    def _prefill_over_cache(self, ids: List[List[int]], matches: List[Any],
-                            all_logits: bool = False):
-        """Gather cached pages + chunked-prefill the uncached suffixes.
+    def _row_bucket(self, n: int, hit: bool) -> int:
+        """The rows a generation launch of ``n`` prompts runs at: the
+        smallest row bucket that holds them, or ``slots`` over a radix
+        hit, whose prefix bucket follows the cache's contents — row
+        buckets there would multiply the programs it compiles on
+        demand."""
+        return self.slots if hit else _bucket(n, self.row_buckets)
 
-        Shared by both engines; they differ only in what happens to the
-        result: dense keeps the returned contiguous slot rows (prefix
-        copied in), paged takes the suffix-only K/V and page-scatters it
-        (the gathered prefix is a transient activation input — the
-        suffix must attend to it — never per-row storage).
-        """
+    def _launch(self, ids: List[List[int]], matches: List[Any],
+                cached: List[int], all_logits: bool = False):
+        """The batch's one prefill launch: whole prompts at a row bucket,
+        or with a radix hit the uncached suffixes by chunked prefill over
+        the gathered cached pages (:meth:`_row_bucket`); scoring
+        launches (``all_logits``) keep ``slots`` rows.  Counted in
+        ``prefill_positions_run``.  The first generation launch of whole
+        prompts at a bucket first compiles the other row buckets
+        (:meth:`_rehearse`)."""
+        seqs = [seq[c:] for seq, c in zip(ids, cached)]
+        L = _bucket(max(map(len, seqs)), self.prefill_buckets)
+        P = (_bucket(max(cached), self._prefix_buckets) if any(cached)
+             else None)
+        rows = (self.slots if all_logits
+                else self._row_bucket(len(ids), P is not None))
+        if not all_logits and P is None and L not in self._rehearsed:
+            self._rehearsed.add(L)
+            self._rehearse([r for r in self.row_buckets if r != rows], L)
+        self.prefill_positions_run += rows * L
+        return self._run(seqs, matches if P is not None else [], rows, L, P,
+                         all_logits)
+
+    def _run(self, seqs: List[List[int]], matches: List[Any], rows: int,
+             L: int, P: Optional[int], all_logits: bool):
+        """Run the prefill program over ``rows`` rows at bucket ``L``:
+        ``seqs`` fill the first rows, the rest are pad rows of one dummy
+        token.  With a prefix bucket ``P``, row ``r``'s cached prefix
+        (``matches[r]``'s pages) is gathered and ``seqs[r]`` is its
+        suffix: dense keeps the returned contiguous rows (prefix copied
+        in), paged takes the suffix-only K/V and page-scatters it (the
+        gathered prefix is a transient activation input — the suffix must
+        attend to it — never per-row storage)."""
+        toks = np.zeros((rows, L), np.int32)
+        vlen = np.ones((rows,), np.int32)  # pad rows: 1 dummy
+        for r, seq in enumerate(seqs):
+            toks[r, : len(seq)] = seq
+            vlen[r] = len(seq)
+        toks, vlen = jnp.asarray(toks), jnp.asarray(vlen)
+        if P is None:
+            if all_logits:
+                fn = self._prefill_bucket_all
+            else:
+                fn = self._prefill_bucket if self.paged else self._prefill
+            return fn(self.params, toks, vlen)
         pc = self.prefix_cache
-        page = pc.page_size
-        suffix_lens = [len(s) - m.length for s, m in zip(ids, matches)]
-        L = _bucket(max(suffix_lens), self.prefill_buckets)
-        P = _bucket(max(m.length for m in matches), self._prefix_buckets)
-        page_ids = np.zeros((self.slots, P // page), np.int32)
-        toks = np.zeros((self.slots, L), np.int32)
-        vlen = np.ones((self.slots,), np.int32)
-        plen = np.zeros((self.slots,), np.int32)
-        for r, (seq, m) in enumerate(zip(ids, matches)):
-            suffix = seq[m.length:]
-            toks[r, : len(suffix)] = suffix
-            vlen[r] = len(suffix)
+        page_ids = np.zeros((rows, P // pc.page_size), np.int32)
+        plen = np.zeros((rows,), np.int32)
+        for r, m in enumerate(matches):
             plen[r] = m.length
             page_ids[r, : len(m.pages)] = m.pages
         kp, vp = pc.pool.gather(page_ids)
-        self.prefill_positions_run += self.slots * L
         if self.paged:
             fn = (self._chunked_prefill_all_paged if all_logits
                   else self._chunked_prefill_paged)
         else:
             fn = (self._chunked_prefill_all if all_logits
                   else self._chunked_prefill)
-        return fn(
-            self.params, jnp.asarray(toks), jnp.asarray(vlen),
-            kp, vp, jnp.asarray(plen),
-        )
+        return fn(self.params, toks, vlen, kp, vp, jnp.asarray(plen))
+
+    def _rehearse(self, row_buckets: List[int], L: int) -> None:
+        """Compile rehearsal: launch pad rows at each of ``row_buckets``
+        × ``L`` and compile what a refill runs after the prefill — the
+        page scatter, run to the dump page, or the dense row insert,
+        compiled ahead of time for the decode state's shapes (no second
+        ``slots`` × ``max_seq`` cache; on a mesh it compiles on demand)
+        — so that no refill of whole prompts at this bucket compiles.  Runs before the real launch,
+        with nothing of it alive, and waits for each launch before the
+        next, so no two launches' buffers are alive at once.  Not
+        counted in ``prefill_positions_run``."""
+        if not row_buckets:
+            return
+        aot = not self.paged and self.mesh is None
+        if aot:
+            # the decode state's shapes: init_state's all-pad prefill
+            i32 = jax.ShapeDtypeStruct((), jnp.int32)
+            dst = jax.eval_shape(
+                self._prefill, self.params,
+                jax.ShapeDtypeStruct((self.slots, self.prefill_buckets[0]),
+                                     jnp.int32),
+                jax.ShapeDtypeStruct((self.slots,), jnp.int32))
+        for rows in row_buckets:
+            cache, logits = self._run([], [], rows, L, None, False)
+            done = cache
+            if self.paged:
+                if not self.pool.bound:
+                    self.pool.bind(cache["k"], cache["v"])
+                self._scatter_rows(cache, [])
+                done = (self.pool.k, self.pool.v)
+            elif aot:
+                # the state is placed as the prefill's outputs are: a
+                # committed placement is part of the compiled program
+                like = jax.tree.map(
+                    lambda d, x: jax.ShapeDtypeStruct(
+                        d.shape, d.dtype,
+                        sharding=x.sharding if x.committed else None),
+                    dst, (cache, logits))
+                self._insert.lower(*like, cache, logits, i32, i32).compile()
+            jax.block_until_ready(done)
+            del cache, logits, done
 
     # ---------------------------- paged path --------------------------
     def _prefill_rows_paged(self, ids: List[List[int]], lens: List[int],
@@ -1013,22 +1098,7 @@ class Engine:
                     page = self._alloc_pages(1)[0]
                     own.append(page)
                     plan.append(page)
-            if any(cached):
-                cache, logits = self._prefill_over_cache(
-                    ids, matches, all_logits=all_logits)
-            else:
-                L = _bucket(max(lens), self.prefill_buckets)
-                toks = np.zeros((self.slots, L), np.int32)
-                vlen = np.ones((self.slots,), np.int32)  # pad rows: 1 dummy
-                for r, seq in enumerate(ids):
-                    toks[r, : len(seq)] = seq
-                    vlen[r] = len(seq)
-                fn = (self._prefill_bucket_all if all_logits
-                      else self._prefill_bucket)
-                cache, logits = fn(
-                    self.params, jnp.asarray(toks), jnp.asarray(vlen)
-                )
-                self.prefill_positions_run += self.slots * L
+            cache, logits = self._launch(ids, matches, cached, all_logits)
             if not self.pool.bound:
                 self.pool.bind(cache["k"], cache["v"])
             self._scatter_rows(cache, chunks)
@@ -1068,7 +1138,7 @@ class Engine:
 
     def _scatter_rows(self, cache: Any,
                       chunks: List[List[Optional[int]]]) -> None:
-        """Page-scatter prefilled K/V ``(layers, slots, L, KV, hd)`` into
+        """Page-scatter prefilled K/V ``(layers, rows, L, KV, hd)`` into
         each row's target pages.  ``chunks[r][c]`` is the pool page for
         row ``r``'s ``c``-th computed page-chunk, or None for chunks
         whose page is written by another row of this batch (in-batch

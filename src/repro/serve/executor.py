@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import random
+import threading
 from collections import deque
 from typing import Deque, Dict, Iterable, Iterator, List, Optional
 
@@ -151,11 +152,12 @@ class ExecutorStats:
     #: score_e2e_s.count == requests_finished, exactly, on any replica
     #: merge (benchmarks/serving_latency.py asserts this)
     requests_finished: int = 0
-    #: positions the engine's prefill programs were launched over (rows ×
-    #: bucket, padding rows included; init_state's warm prefill and score
-    #: batches too) — what the device ran, so cancels and requeues never
-    #: back it out.  prefill_tokens_computed over this is the share of
-    #: prefill work that was prompt
+    #: positions the engine's prefill programs were launched over (row
+    #: bucket × bucket, pad rows included; init_state's warm prefill,
+    #: score batches and radix-hit launches at slots rows) — what the
+    #: device ran, so cancels and requeues never back it out.  Booked
+    #: with prefill_tokens_computed, at once; that over this is the share
+    #: of prefill work that was prompt
     prefill_positions_run: int = 0
     #: cluster submit/cancel paths: acquisitions of this replica's lock
     #: and the seconds spent waiting for them (booked by the Cluster)
@@ -238,6 +240,9 @@ class ContinuousBatchingExecutor:
         self._failstreak = 0  # consecutive failed steps; reset on success
         self._any_deadline = False  # sweep guard: no deadlines, no scans
         self.stats = ExecutorStats()
+        #: held only to book a prefill and to copy ``stats``
+        #: (:meth:`read_stats`), never across a step
+        self._stats_lock = threading.Lock()
         #: request-lifecycle tracing (DESIGN.md §17) — the falsy no-op
         #: recorder unless REPRO_TRACE is set or the owner (cluster,
         #: client, launcher) handed one in.  Stamped from the executor's
@@ -448,7 +453,6 @@ class ContinuousBatchingExecutor:
             self.trace.counter("outstanding_tokens", self.outstanding_tokens,
                                pid=self.trace_pid)
         expired = self._expire_deadlines()
-        launched = self.engine.prefill_positions_run
         try:
             finished = self._step_inner()
         except Exception:
@@ -458,10 +462,6 @@ class ContinuousBatchingExecutor:
                 raise
             self._backoff()
             return expired
-        finally:
-            # booked even when the step failed: the device did the work
-            self.stats.prefill_positions_run += (
-                self.engine.prefill_positions_run - launched)
         self._failstreak = 0
         if self._state is not None and not self.pending:
             # fully idle: release the dense slots × max_seq cache
@@ -831,15 +831,26 @@ class ContinuousBatchingExecutor:
                           finished: List[ServeHandle]) -> None:
         """Prefill the admitted requests as one batch and install each
         row in its slot; book their queue waits once the prefill ran."""
-        if self._state is None:
-            self._state = self.engine.init_state()
-        with self.trace.span("executor.prefill", "executor",
-                             pid=self.trace_pid, rows=len(admitted)) as sp:
-            cache, logits, lens, cached_lens = self.engine.prefill_rows(
-                [h.prompt for h in admitted])
-            if sp is not None:
-                sp["computed"] = int(sum(lens) - sum(cached_lens))
-                sp["cached"] = int(sum(cached_lens))
+        launched = self.engine.prefill_positions_run
+        try:
+            if self._state is None:
+                self._state = self.engine.init_state()
+            with self.trace.span("executor.prefill", "executor",
+                                 pid=self.trace_pid,
+                                 rows=len(admitted)) as sp:
+                cache, logits, lens, cached_lens = self.engine.prefill_rows(
+                    [h.prompt for h in admitted])
+                if sp is not None:
+                    sp["computed"] = int(sum(lens) - sum(cached_lens))
+                    sp["cached"] = int(sum(cached_lens))
+        except Exception:
+            self._book_prefill(launched)   # the device ran it all the same
+            raise
+        self._book_prefill(launched, sum(lens) - sum(cached_lens),
+                           sum(cached_lens))
+        for h, cached in zip(admitted, cached_lens):
+            h._cached_prompt = cached
+            h._prefill_counted = True
         self.stats.prefill_batches += 1
         self.stats.refills += len(admitted)
         qw = self.metrics.histogram("queue_wait_s")
@@ -848,10 +859,6 @@ class ContinuousBatchingExecutor:
             self.stats.queued_s += w
         tok = self.engine.tokenizer
         for row, h in enumerate(admitted):
-            h._cached_prompt = cached_lens[row]
-            self.stats.prefill_tokens_computed += lens[row] - cached_lens[row]
-            self.stats.prefill_tokens_cached += cached_lens[row]
-            h._prefill_counted = True
             self.engine.insert_row(self._state, cache, logits, row, h._slot)
             h._budget = min(h.max_tokens,
                             self.engine.max_seq - h.prompt_tokens - 1)
@@ -870,6 +877,25 @@ class ContinuousBatchingExecutor:
                            if self.engine.spec_decode else None)
             if h._budget <= 0:  # prompt alone fills the context window
                 self._retire(h, "length", finished)
+
+    def _book_prefill(self, launched: int, computed: int = 0,
+                      cached: int = 0) -> None:
+        """Book a prefill at once: the positions the engine launched
+        since its counter read ``launched`` (the device ran them even if
+        the prefill then failed) and the prompt tokens computed and served
+        from the prefix cache."""
+        with self._stats_lock:
+            self.stats.prefill_positions_run += (
+                self.engine.prefill_positions_run - launched)
+            self.stats.prefill_tokens_computed += computed
+            self.stats.prefill_tokens_cached += cached
+
+    def read_stats(self) -> ExecutorStats:
+        """A copy of ``stats`` safe to take from another thread while the
+        executor steps: it never holds a prefill's launched positions
+        without its prompt tokens, or the tokens without the positions."""
+        with self._stats_lock:
+            return ExecutorStats() + self.stats
 
     def _score_refill(self, finished: List[ServeHandle]) -> None:
         """Admit and retire queued score requests (DESIGN.md §13).
@@ -910,12 +936,14 @@ class ContinuousBatchingExecutor:
                 self._queue.remove(h)
                 self._queued_tokens -= self._need(h)
                 h.status = ACTIVE
+            launched = eng.prefill_positions_run
             try:
                 with self.trace.span("executor.score_batch", "executor",
                                      pid=self.trace_pid, rows=len(batch)):
                     rows = eng.score_rows(
                         [(h.prompt, h.score) for h in batch])
             except Exception:
+                self._book_prefill(launched)
                 # idempotent like generation prefill: back onto the queue
                 # front, count a retry, re-raise into step()'s handler
                 for h in reversed(batch):
@@ -926,15 +954,16 @@ class ContinuousBatchingExecutor:
                     self._queue.appendleft(h)
                     self._queued_tokens += self._need(h)
                 raise
+            cached = sum(row.cached_tokens for row in rows)
+            self._book_prefill(
+                launched, sum(h.prompt_tokens for h in batch) - cached,
+                cached)
             self.stats.prefill_batches += 1
             self.stats.score_requests += len(batch)
             done_ts = self.clock.now()
             se = self.metrics.histogram("score_e2e_s")
             for h, row in zip(batch, rows):
                 self.stats.scored_tokens += row.cont_tokens
-                self.stats.prefill_tokens_computed += (
-                    h.prompt_tokens - row.cached_tokens)
-                self.stats.prefill_tokens_cached += row.cached_tokens
                 h.result = GenResult(
                     text="", prompt_tokens=h.prompt_tokens,
                     completion_tokens=0, finish_reason="score",

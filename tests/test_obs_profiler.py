@@ -132,9 +132,10 @@ def test_profiled_window_holds_the_program_spans_nested(params, tmp_path,
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
 def test_prefill_positions_run_is_rows_times_bucket(params, paged,
                                                     monkeypatch):
-    """slots × bucket per prefill launch, padding rows included; the dense
-    engine also launches its warm all-pad prefill at each ``init_state``;
-    a score batch launches one prefill of its own."""
+    """row bucket × bucket per prefill launch, pad rows included; the
+    dense engine also launches its warm all-pad prefill (slots rows) at
+    each ``init_state``; a score batch launches one prefill of its own,
+    at slots rows."""
     monkeypatch.delenv("REPRO_CHAOS", raising=False)
     eng = engine(params, paged=paged, slots=4)
     assert eng.prefill_buckets == [128, 256]
@@ -147,8 +148,10 @@ def test_prefill_positions_run_is_rows_times_bucket(params, paged,
     ex.submit_score(prompt(60), "Yes")
     ex.drain()
     buckets = [128, 256, 256, 128]   # by the longest row; the score batch
+    rows = [2, 1, 4, 4]   # row buckets of 2, 1 and 3 rows; the score: slots
     warm = 0 if paged else 3 * 4 * 128   # one init_state per drained batch
-    assert ex.stats.prefill_positions_run == 4 * sum(buckets) + warm
+    assert ex.stats.prefill_positions_run == (
+        sum(r * b for r, b in zip(rows, buckets)) + warm)
     assert ex.stats.prefill_batches == 4
     assert ex.stats.prefill_tokens_computed == sum(map(sum, batches)) + 60 + 3
 
@@ -161,7 +164,42 @@ def test_cancel_does_not_back_out_prefill_positions(params, monkeypatch):
     assert ex.stats.prefill_tokens_computed == 90
     ex.cancel(h)
     assert ex.stats.prefill_tokens_computed == 0
-    assert ex.stats.prefill_positions_run == 2 * 128
+    assert ex.stats.prefill_positions_run == 1 * 128   # one row of 2 slots
+
+
+def test_cluster_stats_never_read_a_prefill_half_booked(params, monkeypatch):
+    """Every launch here is one full 128-token row at the 128 bucket, so
+    prompt tokens equal launched positions in every snapshot: the
+    executor books the two together, and ``Cluster.stats`` copies them
+    together, even while the worker steps."""
+    monkeypatch.delenv("REPRO_CHAOS", raising=False)
+    snaps, stop = [], threading.Event()
+    interval = sys.getswitchinterval()
+    with Cluster([engine(params, slots=1)]) as cl:
+        def poll():
+            while not stop.is_set():
+                s = cl.stats()
+                snaps.append((s.prefill_tokens_computed,
+                              s.prefill_positions_run))
+                time.sleep(0)
+
+        pollers = [threading.Thread(target=poll) for _ in range(4)]
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in pollers:
+                t.start()
+            handles = [cl.submit(prompt(128), max_tokens=6)
+                       for _ in range(4)]
+            for h in handles:
+                cl.result(h)
+        finally:
+            stop.set()
+            sys.setswitchinterval(interval)
+            for t in pollers:
+                t.join(timeout=30)
+    assert not any(t.is_alive() for t in pollers)
+    assert snaps[-1] == (4 * 128, 4 * 128)
+    assert all(tok == pos for tok, pos in snaps)
 
 
 class SignallingLock:
