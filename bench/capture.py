@@ -9,7 +9,8 @@ fault injector wraps.  It delegates every call untouched and records:
   row the engine produced at each position it served — the prefill's
   last position, then every decode step — and the token fed at each
   step; these are what ``check.py`` compares with the plain reference;
-* for every step, the positions it computed, from which ``flops.py``
+* for every step, the positions it computed, from which the
+  configuration's architecture module (``bench/arch/``, ``step_flops``)
   counts the model FLOPs of the window.
 
 ``TimedClient`` is the join operators' client: it times every model call

@@ -11,9 +11,11 @@ Three kinds of number, each held to a limit from ``limits/<cell>.json``:
   logits row per served token and the tokens fed to decode equal to the
   answer it returned (``unchecked_requests``, limit 0); one still queued
   at the window's close, and so cancelled, is not compared;
-* their logits against the plain float32 reference run over the same
-  prompt and served tokens: ``logit_kl_max`` and ``logit_kl_mean`` are
-  the largest and the mean, over every compared position, of the KL
+* their logits against the plain float32 reference of the
+  configuration's architecture module (``bench/arch/``,
+  ``reference_logits``), run over the same prompt and served tokens:
+  ``logit_kl_max`` and ``logit_kl_mean`` are the largest and the mean,
+  over every compared position, of the KL
   divergence of the engine's next-token distribution from the
   reference's.  The readings also carry ``logit_gap`` (the widest gap
   between the reference's best logit and its logit of the token the
@@ -28,8 +30,7 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
-from bench import reference
-from bench.weights import sizes
+from bench import arch
 
 BOS, EOS, OFFSET = 1, 2, 4  # the served byte tokenizer: byte + 4
 
@@ -58,7 +59,8 @@ def join_numbers(queries: Sequence) -> Dict[str, int]:
 def logit_numbers(config: dict, seed: int, context: int,
                   tracked: Sequence, batch: int) -> Dict[str, float]:
     """``tracked``: (record, host logits rows, calls) per tracked request."""
-    V = sizes(config)["V"]
+    model = arch.load(config)
+    V = model.vocab(config)
     seqs, want, rows = [], [], []
     unchecked = 0
     for rec, logits, calls in tracked:
@@ -86,8 +88,8 @@ def logit_numbers(config: dict, seed: int, context: int,
            "logit_kl_max": float("inf"), "logit_kl_mean": float("inf")}
     if not seqs:
         return out
-    refs = reference.logits(config, seed, seqs, want, length=context,
-                            batch=batch)
+    refs = model.reference_logits(config, seed, seqs, want, length=context,
+                                  batch=batch)
     gaps, rels, kls = [], [], []
     for eng, ref in zip(rows, refs):
         top = eng.argmax(axis=1)

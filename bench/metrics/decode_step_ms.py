@@ -1,8 +1,8 @@
 """Device time of the engine's decode program per decode step, in ms.
 
 The decode program is the one whose executions in the traced window
-match the executor's decode steps (``trace.decode_program``); XLA names
-it ``jit__lambda(<fingerprint>)`` today, like every engine program.
+match the executor's decode steps (``trace.decode_program``), not its
+name (``jit_engine_decode_paged`` on the cells' paged path).
 Layer: engine step (``serve/engine.py``); source: the profiler trace."""
 
 from bench.trace import decode_program

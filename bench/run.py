@@ -132,21 +132,16 @@ class CompileCounter:
 
 
 def program_config(cfg: dict):
-    """The program's ModelConfig for a configuration file, checked key by
-    key against the file (only the depth and the choice of the Pallas
-    kernels are set from it)."""
+    """The program's ModelConfig for a configuration file: the fields its
+    architecture module sets from the file, then every field the module
+    names checked against the file's number."""
+    from bench import arch
     from repro.configs import get_config
 
+    model = arch.load(cfg)
     pc = dataclasses.replace(get_config(cfg["program_arch"]),
-                             n_layers=cfg["num_hidden_layers"],
-                             use_pallas=cfg["serving"]["use_pallas"])
-    want = {"d_model": cfg["hidden_size"], "n_heads": cfg["num_attention_heads"],
-            "n_kv_heads": cfg["num_key_value_heads"],
-            "resolved_head_dim": cfg["head_dim"], "d_ff": cfg["intermediate_size"],
-            "vocab_size": cfg["vocab_size"], "norm_eps": cfg["rms_norm_eps"],
-            "rope_theta": cfg["rope_theta"],
-            "tie_embeddings": cfg["tie_word_embeddings"]}
-    for k, v in want.items():
+                             **model.program_fields(cfg))
+    for k, v in model.checked_fields(cfg).items():
         if getattr(pc, k) != v:
             raise ValueError(f"program config {k}={getattr(pc, k)} differs "
                              f"from the configuration file's {v}")
@@ -215,7 +210,7 @@ def run_cell(config: dict, mix: dict, seed: int, seconds: float,
     import jax
     import jax.numpy as jnp
 
-    from bench import capture, check, scenarios, traffic, weights
+    from bench import arch, capture, check, scenarios, traffic
     from bench import trace as btrace
     from repro.core.oracle import OracleLLM
     from repro.data.tokenizer import ByteTokenizer
@@ -235,12 +230,13 @@ def run_cell(config: dict, mix: dict, seed: int, seconds: float,
     if control == "fp8_kv":
         pcfg = dataclasses.replace(pcfg, kv_cache_dtype="float8_e4m3fn")
     dtype = jnp.dtype(config["torch_dtype"])
+    model = arch.load(config)
 
-    params = weights.draw_params(config, seed, dtype,
-                                 vocab_rows=pcfg.padded_vocab)
+    params = model.draw_params(config, seed, dtype,
+                               vocab_rows=pcfg.padded_vocab)
     shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, dtype),
                           model_specs(pcfg), is_leaf=is_spec)
-    params = weights.to_program(params, shapes)
+    params = model.to_program(params, shapes)
     if control == "int8":
         params = quantize_leaves(params, model_specs(pcfg))
     jax.block_until_ready(params)

@@ -1,41 +1,47 @@
-"""The plain float32 reference against the program's own forward pass,
-at smoke size on the CPU, with the same bf16-drawn weights."""
+"""Each architecture module's plain float32 reference against the
+program's own forward pass, at smoke size on the CPU, with the same
+bf16-drawn weights."""
 
 import dataclasses
+import importlib
+import pkgutil
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, weights
+from bench import arch
+from bench.arch import dense_gqa
+
+#: (module, program preset, preset changes) for every module under
+#: bench/arch/ and every preset it describes; the id is the preset's name
+SMOKE = [(m.name, preset, over) for m in pkgutil.iter_modules(arch.__path__)
+         for preset, over in importlib.import_module(
+             f"bench.arch.{m.name}").SMOKE_ARCHS.items()]
 
 
-@pytest.mark.parametrize("arch", ["granite-3-2b", "yi-9b"])
-def test_reference_matches_models_forward(arch):
+@pytest.mark.parametrize("module, preset, over", SMOKE,
+                         ids=[p for _, p, _ in SMOKE])
+def test_reference_matches_models_forward(module, preset, over):
     from repro.configs import get_smoke_config
     from repro.models import forward, model_specs
     from repro.models.params import is_spec
 
-    c = dataclasses.replace(get_smoke_config(arch), n_layers=3)
-    cfg = {"num_hidden_layers": c.n_layers, "hidden_size": c.d_model,
-           "num_attention_heads": c.n_heads,
-           "num_key_value_heads": c.n_kv_heads,
-           "head_dim": c.resolved_head_dim, "intermediate_size": c.d_ff,
-           "vocab_size": c.vocab_size,
-           "tie_word_embeddings": c.tie_embeddings,
-           "rms_norm_eps": c.norm_eps, "rope_theta": c.rope_theta}
+    model = importlib.import_module(f"bench.arch.{module}")
+    c = dataclasses.replace(get_smoke_config(preset), **over)
+    cfg = model.file_config(c)
     seed = 2**34 + 99
-    drawn = weights.draw_params(cfg, seed, jnp.bfloat16,
-                                vocab_rows=c.padded_vocab)
+    drawn = model.draw_params(cfg, seed, jnp.bfloat16,
+                              vocab_rows=c.padded_vocab)
     shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32),
                           model_specs(c), is_leaf=is_spec)
     params = jax.tree.map(lambda a: a.astype(jnp.float32),
-                          weights.to_program(drawn, shapes))
+                          model.to_program(drawn, shapes))
     rng = np.random.default_rng(0)
     seqs = [list(rng.integers(4, 260, n)) for n in (37, 100)]
     want = [list(range(30, 37)), [0, 50, 99]]
-    ref = reference.logits(cfg, seed, seqs, want, length=512, batch=3)
+    ref = model.reference_logits(cfg, seed, seqs, want, length=512, batch=3)
     with jax.default_matmul_precision("highest"):
         for s, w, r in zip(seqs, want, ref):
             lg, _ = forward(c, params, {"tokens": jnp.asarray([s])})
@@ -57,18 +63,13 @@ def test_leafwise_int8_matches_the_programs_quantization():
     from bench import run
 
     c = dataclasses.replace(get_smoke_config("yi-9b"), n_layers=2)
-    cfg = {"num_hidden_layers": 2, "hidden_size": c.d_model,
-           "num_attention_heads": c.n_heads,
-           "num_key_value_heads": c.n_kv_heads,
-           "head_dim": c.resolved_head_dim, "intermediate_size": c.d_ff,
-           "vocab_size": c.vocab_size,
-           "tie_word_embeddings": c.tie_embeddings}
+    cfg = dense_gqa.file_config(c)
     specs = model_specs(c)
     shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16),
                           specs, is_leaf=is_spec)
 
     def draw():
-        return weights.to_program(weights.draw_params(
+        return dense_gqa.to_program(dense_gqa.draw_params(
             cfg, 5, jnp.bfloat16, vocab_rows=c.padded_vocab), shapes)
 
     want = quantize_params(draw(), specs)

@@ -27,6 +27,7 @@ TINY = {"name": "tiny", "program_arch": "granite-3-2b",
         "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
         "vocab_size": 515, "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
         "tie_word_embeddings": True, "torch_dtype": "bfloat16",
+        "bench_arch": "dense_gqa",
         "serving": {"max_seq": 1024, "slots": 4, "page_size": 16,
                     "pool_pages": 256, "use_pallas": False}}
 MIX = {"name": "toy", "operator": "block_join", "prefix_cache": False,

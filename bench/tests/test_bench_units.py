@@ -1,6 +1,8 @@
-"""CPU checks of the benchmark's yardstick: FLOP and byte counts, the
-trace reduction, traffic draws, pair crediting and the manifest."""
+"""CPU checks of the benchmark's yardstick: the dense architecture
+module's weights and FLOP and byte counts, the trace reduction, traffic
+draws, pair crediting and the manifest."""
 
+import hashlib
 import json
 import math
 import re
@@ -8,7 +10,8 @@ from pathlib import Path
 
 import pytest
 
-from bench import flops, scenarios, trace, traffic, weights
+from bench import arch, scenarios, trace, traffic, weights
+from bench.arch import dense_gqa
 
 ROOT = Path(__file__).resolve().parents[2]
 MAN = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -25,18 +28,18 @@ YI = json.loads((ROOT / "bench/configs/yi-9b-24l.json").read_text())
     (YI, 33_554_432 + 8_388_608 + 33_554_432 + 270_532_608, 24),
 ])
 def test_linear_flops_match_hand_count(cfg, per_layer, layers):
-    assert flops.linear_flops_per_token(cfg) == per_layer * layers
+    assert dense_gqa.linear_flops_per_token(cfg) == per_layer * layers
 
 
 def test_span_flops_counts_attention_at_each_position():
     cfg = GRANITE
     # positions 10..13 attend 11+12+13+14 = 50 keys; logits once
-    want = (4 * flops.linear_flops_per_token(cfg)
+    want = (4 * dense_gqa.linear_flops_per_token(cfg)
             + 40 * 4 * 32 * 64 * 50 + 2 * 2048 * 49155)
-    assert flops.span_flops(cfg, 10, 14) == want
-    assert flops.span_flops(cfg, 5, 5) == 0
-    assert flops.step_flops(cfg, [(10, 14), (0, 1)]) == (
-        want + flops.span_flops(cfg, 0, 1))
+    assert dense_gqa.span_flops(cfg, 10, 14) == want
+    assert dense_gqa.span_flops(cfg, 5, 5) == 0
+    assert dense_gqa.step_flops(cfg, [(10, 14), (0, 1)]) == (
+        want + dense_gqa.span_flops(cfg, 0, 1))
 
 
 @pytest.mark.parametrize("cfg, params, kv_per_token", [
@@ -48,9 +51,9 @@ def test_span_flops_counts_attention_at_each_position():
     (YI, 24 * 173_023_232 + 524_288_000 + 4096, 49_152),
 ])
 def test_params_and_kv_bytes_match_hand_count(cfg, params, kv_per_token):
-    assert flops.param_count(cfg) == params
-    assert flops.kv_bytes_per_token(cfg) == kv_per_token
-    assert flops.decode_step_bytes(cfg, [100, 50]) == (
+    assert dense_gqa.param_count(cfg) == params
+    assert dense_gqa.kv_bytes_per_token(cfg) == kv_per_token
+    assert dense_gqa.decode_step_bytes(cfg, [100, 50]) == (
         2 * params + 150 * kv_per_token)
 
 
@@ -116,7 +119,21 @@ def test_kl_divergence_of_logits():
 
 def test_summarize_without_a_trace_reports_nothing_measured(tmp_path):
     s = trace.summarize(tmp_path, 2.0)
-    assert s["busy_s"] is None and s["programs"] == {}
+    assert s["busy_s"] is None and s["programs"] == {} and s["ops"] == {}
+
+
+def test_op_table_counts_every_op_by_its_short_name():
+    """Every op of the window, not only the ten the breakdown keeps, with
+    its executions and device seconds, keyed as ``device_ops`` keys it."""
+    a = "%fusion.1 = bf16[6,4096]{1,0:T(8,128)} fusion(%p), kind=kLoop"
+    ops = ([(i * 100, i * 100 + 40, a) for i in range(3)]
+           + [(0, 2_000_000_000, "%while.3 = (s32[]{:T(128)}) while()")]
+           + [(5, 5 + 7 * i, f"%copy.{i}") for i in range(1, 13)])
+    got = trace.op_table(ops)
+    assert got[trace.short_name(a)] == [3, 120e-9]
+    assert got["%while.3 = (s32[]) while()"] == [1, 2.0]
+    assert got["%copy.12"] == [1, 84e-9]
+    assert len(got) == 14
 
 
 # ------------------------------------------------------------------ traffic
@@ -239,21 +256,99 @@ def test_pairs_are_credited_when_their_call_settles():
     assert memo.settled_at[(0, 5, 0, 1)] == t_a
 
 
+SMALL = dict(YI, num_hidden_layers=2, hidden_size=64, intermediate_size=96,
+             num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+             vocab_size=300)
+
+
 def test_weights_slice_alone_equals_slice_in_tree():
     import jax
     import jax.numpy as jnp
 
-    cfg = dict(GRANITE, num_hidden_layers=2, hidden_size=64,
-               intermediate_size=96, num_attention_heads=4,
-               num_key_value_heads=2, head_dim=16, vocab_size=300)
+    cfg = dict(SMALL, tie_word_embeddings=True)
     seed = 2**35 + 17
-    tree = weights.draw_params(cfg, seed, jnp.bfloat16, vocab_rows=304)
+    tree = dense_gqa.draw_params(cfg, seed, jnp.bfloat16, vocab_rows=304)
     key = weights.root_key(seed)
-    one = jax.jit(lambda k: weights.draw_slice(
+    one = jax.jit(lambda k: dense_gqa.draw_slice(
         cfg, k, "w_down", 1, jnp.bfloat16))(key)
     assert bool((one == tree["layers"]["w_down"][1]).all())
     assert tree["embed"].shape == (304, 64)
     assert float(abs(tree["embed"][300:]).max()) == 0.0
+
+
+def _digest(a) -> str:
+    import jax
+    import numpy as np
+
+    bits = np.asarray(jax.device_get(a)).view(np.uint16)
+    return hashlib.sha256(bits.tobytes()).hexdigest()[:16]
+
+
+#: sha256 (first 16 hex digits) of the bf16 bits of slice 0 of each
+#: leaf, at seed 2**33 + 1234, as the draw stood when the cells' limits
+#: were read (bench/weights.py of the first benchmark)
+FROZEN_SLICES = {
+    ("granite-3-2b-nomult", "wq"): "0d9abe5afb121061",
+    ("granite-3-2b-nomult", "embed"): "55fb7a722020fcb7",
+    ("granite-3-2b-nomult", "final_norm"): "83b34fbf30cb8edc",
+    ("granite-3-2b-nomult", "w_down"): "57f21bc0795abca1",
+    ("yi-9b-24l", "wq"): "d4e9921e518b75b1",
+    ("yi-9b-24l", "embed"): "45c56288e0c8505c",
+    ("yi-9b-24l", "final_norm"): "9241de87a450baff",
+    ("yi-9b-24l", "w_down"): "dd74fb21f54e6f79",
+    ("yi-9b-24l", "unembed"): "5cf3669bb8574af0",
+}
+
+
+@pytest.mark.parametrize("config, leaf", sorted(FROZEN_SLICES))
+def test_cell_weights_are_the_frozen_draw(config, leaf):
+    """The cells' weights, bit for bit, at their published widths."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = json.loads((ROOT / f"bench/configs/{config}.json").read_text())
+    model = arch.load(cfg)
+    got = jax.jit(lambda k: model.draw_slice(cfg, k, leaf, 0, jnp.bfloat16))(
+        weights.root_key(2**33 + 1234))
+    assert _digest(got) == FROZEN_SLICES[config, leaf]
+
+
+#: the same digests of every leaf of ``draw_params`` at ``SMALL``
+FROZEN_TREE = {
+    "['embed']": "e16b095d6df3a191", "['final_norm']": "0d729f50bf67fe8b",
+    "['layers']['attn_norm']": "a65a320fe1e6b6d7",
+    "['layers']['mlp_norm']": "1e444d71f3351278",
+    "['layers']['w_down']": "955edd3088e1825e",
+    "['layers']['w_gate']": "9e7acc51c75dd428",
+    "['layers']['w_up']": "f136cca8434d818c",
+    "['layers']['wk']": "d2be3487a578cf95",
+    "['layers']['wo']": "6acc4763b47a276a",
+    "['layers']['wq']": "64c8c9673ed64605",
+    "['layers']['wv']": "81593606a2ac413e",
+    "['unembed']": "05c82fd0aec05e5c",
+}
+
+
+def test_whole_tree_is_the_frozen_draw():
+    import jax
+    import jax.numpy as jnp
+
+    tree = dense_gqa.draw_params(SMALL, 2**35 + 17, jnp.bfloat16,
+                                 vocab_rows=304)
+    got = {jax.tree_util.keystr(p): _digest(a)
+           for p, a in jax.tree_util.tree_leaves_with_path(tree)}
+    assert got == FROZEN_TREE
+
+
+def test_architecture_modules_are_found_by_name():
+    import bench.tests.test_bench_units as me
+
+    assert arch.load(GRANITE) is dense_gqa and arch.load(YI) is dense_gqa
+    assert arch.load({"bench_arch": "bench.tests.test_bench_units"}) is me
+    with pytest.raises(KeyError):
+        arch.load({"hidden_size": 64})
+    with pytest.raises(ModuleNotFoundError):
+        arch.load({"bench_arch": "no_such_architecture"})
 
 
 # ----------------------------------------------------------------- manifest

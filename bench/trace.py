@@ -8,11 +8,16 @@ plane holds the benchmark's own spans around the engine's slot-API calls
 (``bench.prefill_rows``, ``bench.decode_active``, ``bench.insert_row``;
 see ``capture.py``).  Host and device events share one clock.
 
-XLA names the engine's programs ``jit__lambda(<fingerprint>)`` today
-(each is a ``jax.jit`` of a lambda; the fingerprint changes with the
-program), and the eager reshape of prefill's K/V before the page scatter
-``jit_reshape(<fingerprint>)``.  So the decode step's program is told
-apart by how often it ran (``decode_program``), not by its name.
+The engine names its programs ``jit_engine_*`` (``jit_engine_decode_paged``,
+``jit_engine_prefill_bucket_all``, ...; ``Engine._mjit``), the KV pool
+its own ``jit_kv_pool_*``, and XLA the eager reshape of prefill's K/V
+before the page scatter ``jit_reshape``.  The decode step's program is
+still told apart by how often it ran (``decode_program``), not by its
+name.
+
+Readers get, under ``ops``, every device operation of the window with
+its executions and device seconds (``op_table``), so a kernel's time can
+be read whether or not it is among the ten the breakdown shows.
 """
 
 from __future__ import annotations
@@ -124,6 +129,16 @@ def read_events(path: str):
     return modules, ops, host
 
 
+def op_table(ops: Sequence[Tuple[int, int, str]]) -> Dict[str, List]:
+    """``{short_name(op): [executions, device seconds]}`` of op events."""
+    ns: Dict[str, List[int]] = defaultdict(lambda: [0, 0])
+    for a, b, name in ops:
+        row = ns[short_name(name)]
+        row[0] += 1
+        row[1] += b - a
+    return {k: [n, t / 1e9] for k, (n, t) in ns.items()}
+
+
 def clip(events: Sequence[Tuple[int, int, str]], lo: int, hi: int):
     """The parts of ``events`` that lie inside [lo, hi)."""
     return [(max(a, lo), min(b, hi), n) for a, b, n in events
@@ -131,8 +146,8 @@ def clip(events: Sequence[Tuple[int, int, str]], lo: int, hi: int):
 
 
 def summarize(tdir, window_s: float) -> dict:
-    """Busy and idle time, device time by program, and the breakdown the
-    result line carries, for the traced window.
+    """Busy and idle time, device time by program and by operation, and
+    the breakdown the result line carries, for the traced window.
 
     The window is the host span ``WINDOW_SPAN`` that the run opens right
     after the profiler starts and closes right before it stops; device
@@ -142,7 +157,7 @@ def summarize(tdir, window_s: float) -> dict:
     files = glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True)
     if not files:
         return {"busy_s": None, "window_s": window_s, "programs": {},
-                "breakdown": {"device_ops": [], "idle_gaps": []}}
+                "ops": {}, "breakdown": {"device_ops": [], "idle_gaps": []}}
     modules, ops, host = read_events(files[0])
     span = [(a, b) for a, b, n in host if n == WINDOW_SPAN]
     host = [h for h in host if h[2] != WINDOW_SPAN]
@@ -153,15 +168,15 @@ def summarize(tdir, window_s: float) -> dict:
         started = [m for m in modules if lo <= m[0] < hi]
         modules, ops = clip(modules, lo, hi), clip(ops, lo, hi)
     busy = union([(a, b) for a, b, _ in modules])
-    by_op: Dict[str, int] = defaultdict(int)
-    for a, b, name in (ops or modules):
-        by_op[short_name(name)] += b - a
-    idle = label_gaps(gaps(busy), host)
-    top = lambda d: [[k, v / 1e9] for k, v in
+    by_op = op_table(ops or modules)
+    idle = {k: v / 1e9 for k, v in label_gaps(gaps(busy), host).items()}
+    top = lambda d: [[k, v] for k, v in
                      sorted(d.items(), key=lambda kv: -kv[1])[:10]]
     return {
         "busy_s": total(busy) / 1e9 if busy else None,
         "window_s": window_s,
         "programs": programs(started),
-        "breakdown": {"device_ops": top(by_op), "idle_gaps": top(idle)},
+        "ops": by_op,
+        "breakdown": {"device_ops": top({k: t for k, (_, t) in by_op.items()}),
+                      "idle_gaps": top(idle)},
     }
